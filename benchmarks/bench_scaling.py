@@ -40,9 +40,11 @@ def _run_p(p: int, m: int, n: int) -> dict:
                               local_flops=local_flops, wire_bytes=wire,
                               rounds=rounds)))
     """)
+    # The child runs on an explicit CPU platform: it measures virtual-device
+    # structure, and the parent may already hold the chip.
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, timeout=600,
-                         env={"PYTHONPATH": "src",
+                         env={"PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
                               "PATH": "/usr/bin:/bin:/usr/local/bin"})
     return json.loads(res.stdout.strip().splitlines()[-1])
 

@@ -53,6 +53,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.smoke and args.only:
         ap.error("--smoke and --only are mutually exclusive")
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = list(_QR_RECORD_MODULES) if args.smoke else (
         args.only.split(",") if args.only else None)
 

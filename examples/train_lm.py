@@ -22,6 +22,7 @@ the machinery end to end —
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig
 from repro.distributed import StepWatchdog
@@ -57,6 +58,7 @@ def main():
                          "last committed checkpoint (requires "
                          "--fault-tolerance)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)("smollm-135m")
     data = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,

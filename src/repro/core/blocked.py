@@ -199,10 +199,13 @@ from repro.core.plan import MethodSpec, QRConfig, register_method  # noqa: E402
 
 
 def _vmem_geqrf_panel(m: int, n: int, cfg: QRConfig) -> int:
-    """Working set of the widest VMEM-resident panel on the kernel path."""
+    """Working set of the kernel path: the widest VMEM-resident panel or
+    one trailing-update step, whichever is larger."""
     from repro.kernels import ops
 
-    return ops.vmem_bytes_mht_panel(m, min(cfg.block, n))
+    b = min(cfg.block, n)
+    return max(ops.vmem_bytes_mht_panel(m, b),
+               ops.vmem_bytes_wy_trailing(m, b))
 
 
 register_method(MethodSpec(
@@ -222,9 +225,9 @@ register_method(MethodSpec(
 ))
 
 
-def _resolve_geqrf_fori(m: int, n: int, cfg: QRConfig, *, dtype=None
-                        ) -> QRConfig:
-    del dtype  # divisibility is element-width independent
+def _resolve_geqrf_fori(m: int, n: int, cfg: QRConfig, *, dtype=None,
+                        explain=None, backend=None) -> QRConfig:
+    del dtype, explain, backend  # divisibility is all that is checked
     k = min(m, n)
     if k % cfg.block != 0:
         raise ValueError(

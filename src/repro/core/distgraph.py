@@ -59,7 +59,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map, shard_map_unchecked
 from repro.core.tilegraph import tile_grid, tiled_qr
 from repro.core.tsqr import butterfly_merge_r, triangular_inverse_apply
 from repro.distributed.sharding import (
@@ -145,9 +144,9 @@ def _sharded_fn(d: int, tile: int, mode: str, use_kernel: bool, refine: bool,
     out_specs = r_spec if mode == "r" else qr_specs
     # pallas_call has no replication rule: the kernel path must skip the
     # check (outputs are still replicated — the merge ends in a pmax).
-    smap = shard_map_unchecked if use_kernel else shard_map
-    return jax.jit(smap(body, mesh=mesh, in_specs=in_spec,
-                        out_specs=out_specs))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                                 out_specs=out_specs,
+                                 check_vma=not use_kernel))
 
 
 def sharded_tiled_qr(a: Array, *, tile: int = 32, mode: str = "reduced",
@@ -214,7 +213,8 @@ def sharded_tiled_qr(a: Array, *, tile: int = 32, mode: str = "reduced",
 # -- registry -----------------------------------------------------------------
 from repro.core.plan import (  # noqa: E402
     MethodSpec, QRConfig, register_method, sign_fix_qr, sign_fix_r)
-from repro.core.tilegraph import _solve_tiled, _vmem_tiled  # noqa: E402
+from repro.core.tilegraph import (  # noqa: E402
+    _kernel_tile, _solve_tiled, _vmem_tiled)
 
 # Keep each domain's symbolic task DAG within the single-device budget:
 # grow the tile size until the per-domain grid is at most this many tiles
@@ -227,7 +227,7 @@ def _ceil_div(a: int, b: int) -> int:
 
 
 def _resolve_sharded(m: int, n: int, cfg: QRConfig, *, dtype=None,
-                     explain=None) -> QRConfig:
+                     explain=None, backend=None) -> QRConfig:
     from repro.core.plan import RouteDecision
     from repro.observability import metrics as _metrics
 
@@ -265,12 +265,14 @@ def _resolve_sharded(m: int, n: int, cfg: QRConfig, *, dtype=None,
     def domain_grid_side(t: int) -> int:
         return max(domain_rows_of(t), _ceil_div(n, t))
 
+    tile = _kernel_tile(tile, cfg, backend, explain)
+    start = tile
     while domain_grid_side(tile) > _MAX_DOMAIN_GRID and tile < min(m, n):
-        tile = min(2 * tile, m, n)
-    if explain is not None and tile != min(cfg.block, m, n):
+        tile = _kernel_tile(min(2 * tile, m, n), cfg, backend, None)
+    if explain is not None and tile != start:
         explain.append(RouteDecision(
             "sharded_tile_grown", "resolved",
-            f"tile grown {cfg.block} -> {tile} to keep each domain's "
+            f"tile grown {start} -> {tile} to keep each domain's "
             f"grid side <= {_MAX_DOMAIN_GRID} (task count is "
             f"O(p q min(p, q)) per domain)"))
     if cfg.dispatch_mode is None and cfg.use_kernel:

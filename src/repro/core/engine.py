@@ -100,6 +100,18 @@ _KIND_ORDER = ("GEQRT", "LARFB", "TSQRT", "SSRFB")
 #: The engine's kernel lowerings of the static schedule (see module doc).
 DISPATCH_MODES = ("wavefront", "megakernel")
 
+#: Compiled for TPU, the kernels DMA whole ``nb x nb`` tiles out of the
+#: HBM workspace, whose minor dimension is laid out in 128-lane tiles:
+#: Mosaic refuses a tile slice narrower than that ("slice shape along
+#: dimension 3 must be aligned to tiling (128)").  Interpret mode has no
+#: such limit.
+TPU_LANES = 128
+
+
+def lane_aligned_tile(nb: int) -> int:
+    """The smallest tile >= ``nb`` the TPU-compiled lowerings accept."""
+    return -(-nb // TPU_LANES) * TPU_LANES
+
 
 class FactorState(NamedTuple):
     """Factored tile state: packed reflectors + per-task block reflectors.
@@ -483,7 +495,7 @@ def _jnp_wavefront(state: FactorState, by_kind: Dict[str, np.ndarray]
 # ---------------------------------------------------------------------------
 
 def _any_spec():
-    return pl.BlockSpec(memory_space=pltpu.ANY)
+    return pl.BlockSpec(memory_space=pl.ANY)
 
 
 def _dispatch_geqrt(state: FactorState, idx: np.ndarray, nb: int,
@@ -496,14 +508,15 @@ def _dispatch_geqrt(state: FactorState, idx: np.ndarray, nb: int,
         in_specs=[
             _any_spec(),
             pl.BlockSpec((1, nb, nb), lambda g, kk: (kk[g], 0, 0)),
-            pl.BlockSpec((1, nb), lambda g, kk: (kk[g], 0)),
+            _any_spec(),
         ],
         out_specs=[
             _any_spec(),
             pl.BlockSpec((1, nb, nb), lambda g, kk: (kk[g], 0, 0)),
-            pl.BlockSpec((1, nb), lambda g, kk: (kk[g], 0)),
+            _any_spec(),
         ],
         scratch_shapes=[pltpu.VMEM((nb, nb), tiles.dtype),
+                        pltpu.VMEM((nb,), tiles.dtype),
                         pltpu.SemaphoreType.DMA],
     )
     tiles, d_t, d_taus = pl.pallas_call(
@@ -557,16 +570,17 @@ def _dispatch_tsqrt(state: FactorState, idx: np.ndarray, nb: int,
             _any_spec(),
             pl.BlockSpec((1, 1, nb, nb),
                          lambda g, kk, ii: (ii[g], kk[g], 0, 0)),
-            pl.BlockSpec((1, 1, nb), lambda g, kk, ii: (ii[g], kk[g], 0)),
+            _any_spec(),
         ],
         out_specs=[
             _any_spec(),
             pl.BlockSpec((1, 1, nb, nb),
                          lambda g, kk, ii: (ii[g], kk[g], 0, 0)),
-            pl.BlockSpec((1, 1, nb), lambda g, kk, ii: (ii[g], kk[g], 0)),
+            _any_spec(),
         ],
         scratch_shapes=[pltpu.VMEM((nb, nb), tiles.dtype),
                         pltpu.VMEM((nb, nb), tiles.dtype),
+                        pltpu.VMEM((nb,), tiles.dtype),
                         pltpu.SemaphoreType.DMA],
     )
     tiles, t_t, t_taus = pl.pallas_call(
@@ -650,6 +664,13 @@ def _pallas_wavefront(state: FactorState, by_kind: Dict[str, np.ndarray],
 # level boundaries: the first slot of each level fetches synchronously,
 # after every prior write-back has completed — the wavefront barrier.
 
+def _cell(tab_ref, t, col):
+    """Column ``col`` of task-table row ``t``.  The table reaches SMEM
+    flattened to 1-D: SMEM pads a 2-D array's minor dimension to 128
+    words, which would cost 8x the ``_NCOLS``-wide table's bytes."""
+    return tab_ref[t * _NCOLS + col]
+
+
 def _op_copies(tab_ref, t, phase, ws_at, dt_at, tt_at, opbuf, tbuf, sems,
                start: bool):
     """Start (or wait for) the operand DMAs of task-table row ``t`` into
@@ -658,15 +679,15 @@ def _op_copies(tab_ref, t, phase, ws_at, dt_at, tt_at, opbuf, tbuf, sems,
     exactly once per wait.  ``ws_at`` / ``dt_at`` / ``tt_at`` are
     accessor closures over the workspace refs — the batched lowering
     binds the batch index there, the single-matrix one binds nothing."""
-    kind = tab_ref[t, _COL_KIND]
+    kind = _cell(tab_ref, t, _COL_KIND)
 
     def go(cp):
         cp.start() if start else cp.wait()
 
     def tile_fetch(b):
-        r = tab_ref[t, _COL_R0 + 2 * b]
-        c = tab_ref[t, _COL_R0 + 2 * b + 1]
-        reuse = tab_ref[t, _COL_REUSE0 + b]
+        r = _cell(tab_ref, t, _COL_R0 + 2 * b)
+        c = _cell(tab_ref, t, _COL_R0 + 2 * b + 1)
+        reuse = _cell(tab_ref, t, _COL_REUSE0 + b)
 
         @pl.when(reuse == 1)
         def _():
@@ -689,7 +710,7 @@ def _op_copies(tab_ref, t, phase, ws_at, dt_at, tt_at, opbuf, tbuf, sems,
         tile_fetch(2)
 
     def t_fetch(src):
-        reuse = tab_ref[t, _COL_REUSET]
+        reuse = _cell(tab_ref, t, _COL_REUSET)
 
         @pl.when(reuse == 1)
         def _():
@@ -702,11 +723,11 @@ def _op_copies(tab_ref, t, phase, ws_at, dt_at, tt_at, opbuf, tbuf, sems,
 
     @pl.when(kind == _KIND_ID["LARFB"])
     def _():
-        t_fetch(dt_at(tab_ref[t, _COL_K]))
+        t_fetch(dt_at(_cell(tab_ref, t, _COL_K)))
 
     @pl.when(kind == _KIND_ID["SSRFB"])
     def _():
-        t_fetch(tt_at(tab_ref[t, _COL_I], tab_ref[t, _COL_K]))
+        t_fetch(tt_at(_cell(tab_ref, t, _COL_I), _cell(tab_ref, t, _COL_K)))
 
 
 def _sync_put(src, dst, sem):
@@ -737,14 +758,14 @@ def _megakernel_step(tab_ref, ws, d_t, d_taus, t_t, t_taus,
 
     t = lvl * pl.num_programs(nslots_axis) + slot
     phase = jax.lax.rem(t, 2)
-    kind = tab_ref[t, _COL_KIND]
-    k = tab_ref[t, _COL_K]
-    i = tab_ref[t, _COL_I]
-    j = tab_ref[t, _COL_J]
+    kind = _cell(tab_ref, t, _COL_KIND)
+    k = _cell(tab_ref, t, _COL_K)
+    i = _cell(tab_ref, t, _COL_I)
+    j = _cell(tab_ref, t, _COL_J)
     valid = kind != _NOOP
 
     # -- operands: self-fetch at level heads, else already in flight ----
-    @pl.when(valid & (tab_ref[t, _COL_FETCHED] == 0))
+    @pl.when(valid & (_cell(tab_ref, t, _COL_FETCHED) == 0))
     def _():
         _op_copies(tab_ref, t, phase, ws_at, dt_at, tt_at, opbuf, tbuf,
                    sems, start=True)
@@ -755,7 +776,7 @@ def _megakernel_step(tab_ref, ws, d_t, d_taus, t_t, t_taus,
                    sems, start=False)
 
     # -- double buffering: start the successor's fetches before compute -
-    @pl.when(tab_ref[t, _COL_PREFETCH] == 1)
+    @pl.when(_cell(tab_ref, t, _COL_PREFETCH) == 1)
     def _():
         _op_copies(tab_ref, t + 1, 1 - phase, ws_at, dt_at, tt_at, opbuf,
                    tbuf, sems, start=True)
@@ -853,7 +874,7 @@ def _dispatch_megakernel(state: FactorState, p: int, q: int, nb: int,
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in state],
         input_output_aliases={1: 0, 2: 1, 3: 2, 4: 3, 5: 4},
         interpret=interpret,
-    )(jnp.asarray(table_np), *state)
+    )(jnp.asarray(table_np.reshape(-1)), *state)
     return FactorState(*outs)
 
 
@@ -886,7 +907,7 @@ def _dispatch_megakernel_batched(state: FactorState, p: int, q: int,
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in state],
         input_output_aliases={1: 0, 2: 1, 3: 2, 4: 3, 5: 4},
         interpret=interpret,
-    )(jnp.asarray(table_np), *state)
+    )(jnp.asarray(table_np.reshape(-1)), *state)
     return FactorState(*outs)
 
 
@@ -894,18 +915,25 @@ def _dispatch_megakernel_batched(state: FactorState, p: int, q: int,
 # the factor loop
 # ---------------------------------------------------------------------------
 
+def initial_state(tiles: Array, p: int, q: int, nb: int) -> FactorState:
+    """The workspace ``tiles`` (``(..., p, q, nb, nb)``, any leading batch
+    dims) with zeroed block-reflector state beside it."""
+    r = min(p, q)
+    lead = tiles.shape[:-4]
+    dt = tiles.dtype
+    return FactorState(
+        tiles,
+        jnp.zeros(lead + (r, nb, nb), dt),
+        jnp.zeros(lead + (r, nb), dt),
+        jnp.zeros(lead + (p, r, nb, nb), dt),
+        jnp.zeros(lead + (p, r, nb), dt),
+    )
+
+
 def _factor_impl(tiles: Array, p: int, q: int, nb: int, use_kernel: bool,
                  interpret: bool, dispatch_mode: str = "wavefront"
                  ) -> FactorState:
-    r = min(p, q)
-    dt = tiles.dtype
-    state = FactorState(
-        tiles,
-        jnp.zeros((r, nb, nb), dt),
-        jnp.zeros((r, nb), dt),
-        jnp.zeros((p, r, nb, nb), dt),
-        jnp.zeros((p, r, nb), dt),
-    )
+    state = initial_state(tiles, p, q, nb)
     if use_kernel and dispatch_mode == "megakernel":
         with _profiler.annotate(_profiler.megakernel_label(p, q)):
             return _dispatch_megakernel(state, p, q, nb, interpret)
@@ -944,15 +972,7 @@ def _factor_batched_impl(tiles: Array, p: int, q: int, nb: int,
                              dispatch_mode)
         return FactorState(*(x[None] for x in state))
     if use_kernel and dispatch_mode == "megakernel":
-        r = min(p, q)
-        dt = tiles.dtype
-        state = FactorState(
-            tiles,
-            jnp.zeros((batch, r, nb, nb), dt),
-            jnp.zeros((batch, r, nb), dt),
-            jnp.zeros((batch, p, r, nb, nb), dt),
-            jnp.zeros((batch, p, r, nb), dt),
-        )
+        state = initial_state(tiles, p, q, nb)
         with _profiler.annotate(_profiler.megakernel_label(p, q, batch)):
             return _dispatch_megakernel_batched(state, p, q, nb, interpret)
     return jax.vmap(

@@ -106,6 +106,7 @@ __all__ = [
     "kernel_table_budget",
     "DEFAULT_VMEM_BUDGET",
     "DEFAULT_TABLE_BUDGET",
+    "f32_products",
     "sign_fix_qr",
     "sign_fix_r",
 ]
@@ -119,9 +120,17 @@ _Q_METHODS = ("formq", "solve")
 # cannot drift apart.
 DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
 
+# Scoped VMEM the panel and trailing kernels ask the TPU compiler for.
+# Its default (16 MiB on v5e, of 128 MiB) is too small for a working set
+# at the budget above once Pallas double-buffers the blocks and the body
+# adds its temporaries: wy_trailing at m=4096 needs 16.7 MiB.
+KERNEL_VMEM_LIMIT = 4 * DEFAULT_VMEM_BUDGET
+
 # Scalar-prefetch (SMEM) budget for persistent task tables — the limit
 # the engine's megakernel dispatch mode must fit its flattened schedule
-# into (a 16x16 tile grid's table is ~200 KiB; SMEM is ~1 MiB/core).
+# into.  A v5e core has 1 MiB of SMEM (the limit its compiler reports);
+# the largest square grid this budget admits, 21x21 (480 KiB), compiles
+# for v5e (tests/test_tpu_compile.py).
 DEFAULT_TABLE_BUDGET = 512 * 1024
 
 # Matrices at least this large on their short side (and near-square, see
@@ -263,10 +272,13 @@ class MethodSpec:
              :func:`repro.core.engine.factor_tiles_batched` dispatch
              (megakernel mode: one ``pallas_call`` for the stack).
              Deeper batch dims still vmap down to this rule.
-    resolve: optional ``(m, n, cfg, *, dtype) -> cfg`` hook filling
-             method-specific fields (TSQR uses it to pick ``nblocks``;
-             the tiled backends use ``dtype`` — the planned element
-             width — to resolve the engine dispatch mode).
+    resolve: optional ``(m, n, cfg, *, dtype, explain, backend) -> cfg``
+             hook filling method-specific fields (TSQR uses it to pick
+             ``nblocks``; the tiled backends use ``dtype`` — the planned
+             element width — to resolve the engine dispatch mode, and
+             ``backend`` to align the kernel tile on TPU).  It may append
+             :class:`RouteDecision` records to ``explain`` (a list, or
+             None when no trail is kept).
     vmem_bytes: optional ``(m, n, cfg) -> bytes`` working-set estimator
              used by the kernel dispatch policy.
     kernel_policy: name of the :class:`KernelPolicy` whose budget gates
@@ -852,12 +864,9 @@ def plan(shape, dtype=jnp.float32, config: Optional[QRConfig] = None, *,
         resolved = _apply_tuned_config(resolved, cfg, tuned, decisions)
     if spec.resolve is not None:
         # Resolve hooks may append RouteDecisions (dispatch-mode choices,
-        # domain degradations); hooks predating the kwarg still work.
-        try:
-            resolved = spec.resolve(m, n, resolved, dtype=np.dtype(dtype),
-                                    explain=decisions)
-        except TypeError:
-            resolved = spec.resolve(m, n, resolved, dtype=np.dtype(dtype))
+        # tile alignment, domain degradations).
+        resolved = spec.resolve(m, n, resolved, dtype=np.dtype(dtype),
+                                explain=decisions, backend=backend)
     _metrics.counter("planner.plans", method=name).inc()
     record = None
     if explain:
@@ -878,6 +887,21 @@ def plan(shape, dtype=jnp.float32, config: Optional[QRConfig] = None, *,
 # ---------------------------------------------------------------------------
 # solver
 # ---------------------------------------------------------------------------
+
+def f32_products(fn: Callable) -> Callable:
+    """Trace a QR entry point with float32 products at full float32
+    precision.  XLA on TPU runs a float32 product as one bfloat16 pass
+    unless told otherwise, which leaves the residual and the
+    orthogonality of Q near bfloat16's epsilon instead of float32's.
+    The setting is read at trace time, so a solve traced inside an outer
+    ``jit`` (the optimizer step) gets it too.  CPU float32 and every
+    float64 product are unaffected."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
+
 
 def _default_solve(spec: MethodSpec, a: Array, cfg: QRConfig):
     """Derive per-mode output from a packed ``factor`` realization."""
@@ -959,6 +983,7 @@ class QRSolver:
 
     # -- public ------------------------------------------------------------
 
+    @f32_products
     def solve(self, a: Array):
         """Factorize per ``config.mode``: (Q, R), R only, or full (Q, R).
 
@@ -975,6 +1000,7 @@ class QRSolver:
             return f(self._cast(a))
         return self._batched(self._solve2d, a)
 
+    @f32_products
     def factor(self, a: Array):
         """LAPACK packed form ``(packed, taus)`` (methods that have one)."""
         if self.spec.factor is None:
@@ -991,6 +1017,7 @@ class QRSolver:
         q, _ = solver.solve(a)
         return q
 
+    @f32_products
     def lstsq(self, a: Array, b: Array) -> Array:
         """Least-squares solve ``min ||a x - b||`` via this realization."""
         from jax.scipy.linalg import solve_triangular

@@ -456,10 +456,30 @@ def _resolve_dispatch_explained(p: int, q: int, nb: int, itemsize: int,
     return mode
 
 
+def _kernel_tile(tile: int, cfg: QRConfig, backend, explain) -> int:
+    """The tile the planned path can run at: a TPU-compiled kernel needs
+    a lane-aligned tile (:func:`repro.core.engine.lane_aligned_tile`),
+    so a narrower one is raised and the matrix zero-pads to the grid."""
+    if not (cfg.use_kernel and backend == "tpu"):
+        return tile
+    aligned = engine.lane_aligned_tile(tile)
+    if aligned != tile and explain is not None:
+        from repro.core.plan import RouteDecision
+
+        explain.append(RouteDecision(
+            "tpu_tile_lane_aligned", "resolved",
+            f"tile {tile} -> {aligned}: the TPU compiler refuses a DMA of "
+            f"a tile narrower than the {engine.TPU_LANES}-lane HBM tiling "
+            f"of the engine workspace"))
+    return aligned
+
+
 def _resolve_tiled(m: int, n: int, cfg: QRConfig, *, dtype=None,
-                   explain=None) -> QRConfig:
-    # cfg.block doubles as the tile size; never exceed the matrix itself.
-    cfg = cfg.replace(block=min(cfg.block, m, n))
+                   explain=None, backend=None) -> QRConfig:
+    # cfg.block doubles as the tile size; never exceed the matrix itself
+    # (except to align a TPU kernel's tile).
+    cfg = cfg.replace(block=_kernel_tile(min(cfg.block, m, n), cfg, backend,
+                                         explain))
     if cfg.dispatch_mode is None and cfg.use_kernel:
         # Record the engine lowering the kernel path will actually run
         # (megakernel iff the task table + working set fit the budgets

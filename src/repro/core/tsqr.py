@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.scipy.linalg import solve_triangular
 
-from repro.compat import axis_size
 from repro.core.blocked import geqrf
 from repro.core.householder import unpack_r
 
@@ -130,7 +129,7 @@ def butterfly_merge_r(r: Array, axis_name: str, combine) -> Array:
     meshes here are 16/32-way; the sharded-tiled planner rounds its
     domain count down to a power of two).
     """
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if p & (p - 1):
         raise ValueError(f"butterfly_merge_r needs power-of-two axis, got {p}")
     rounds = p.bit_length() - 1
@@ -201,8 +200,8 @@ def default_nblocks(m: int, n: int) -> int:
 
 
 def _resolve_tsqr(m: int, n: int, cfg: QRConfig, *, dtype=None,
-                  explain=None) -> QRConfig:
-    del dtype  # tree shape is element-width independent
+                  explain=None, backend=None) -> QRConfig:
+    del dtype, backend  # tree shape is element-width independent
     nb = cfg.nblocks if cfg.nblocks is not None else default_nblocks(m, n)
     if m % nb != 0:
         raise ValueError(f"m={m} not divisible by nblocks={nb}")

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
-
 Array = jax.Array
 
 __all__ = ["quantize", "dequantize", "ef_compress_tree", "compressed_psum",
@@ -90,7 +88,7 @@ def compressed_psum(tree: Any, axis_name: str, error: Any) -> Tuple[Any, Any]:
     = 1 byte/element vs 4), scales are psum'd in fp32 (1/256 of the
     elements), and every shard decodes sum(codes_i * scale_i) / N — an
     unbiased-in-the-limit mean with local error feedback."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
 
     def one(g, e):
         target = g.astype(jnp.float32) + e

@@ -20,7 +20,7 @@ inner loops.  ``macro_ops`` is the single RDP-analogue:
     Pallas bodies the engine (:mod:`repro.core.engine`) dispatches, one
     ``pallas_call`` per (wavefront, kind) task batch.  Tiles move
     HBM -> VMEM scratch -> HBM by explicit DMA against a ``(p, q, nb,
-    nb)`` workspace held in ``pltpu.ANY`` memory space and aliased
+    nb)`` workspace held in ``pl.ANY`` memory space and aliased
     in-place; task coordinates arrive as scalar-prefetch index arrays.
   * **VMEM estimators** — :func:`vmem_bytes` per op and
     :func:`engine_vmem_bytes` for the engine's worst case, registered as
@@ -50,7 +50,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.blocked import larft, unpack_v_panel
 from repro.core.plan import (DEFAULT_TABLE_BUDGET, DEFAULT_VMEM_BUDGET,
                              KernelPolicy, register_kernel_policy)
 
@@ -64,6 +63,7 @@ __all__ = [
     "reflector_coeffs",
     "panel_body",
     "wy_body",
+    "larft_body",
     "stacked_larft",
     "geqrt_body",
     "larfb_body",
@@ -85,6 +85,21 @@ __all__ = [
 def default_interpret() -> bool:
     """Kernel dispatch default: compiled on TPU, interpret elsewhere."""
     return jax.default_backend() != "tpu"
+
+
+# Products run at the accumulation dtype's own precision: left at the
+# default, a float32 product compiled for TPU may take a single bfloat16
+# pass, which puts the factorization's error near bfloat16's epsilon.
+_EXACT = lax.Precision.HIGHEST
+
+
+def _tdot(x: Array, y: Array) -> Array:
+    """``x^T y`` as one contraction over rows.  A separate transpose op
+    is folded into the product in some programs and not in others, which
+    changes the summation order between the kernel and its oracle."""
+    return lax.dot_general(x, y, (((0,), (0,)), ((), ())),
+                           precision=_EXACT,
+                           preferred_element_type=acc_dtype(x.dtype))
 
 
 def acc_dtype(dtype) -> jnp.dtype:
@@ -164,16 +179,64 @@ def wy_body(v: Array, t: Array, c: Array) -> Array:
     acc = acc_dtype(c.dtype)
     v_a = v.astype(acc)
     c_a = c.astype(acc)
-    w = jnp.dot(v_a.T, c_a, preferred_element_type=acc)
-    w = jnp.dot(t.astype(acc).T, w, preferred_element_type=acc)
-    return (c_a - jnp.dot(v_a, w, preferred_element_type=acc)).astype(c.dtype)
+    w = _tdot(t.astype(acc), _tdot(v_a, c_a))
+    return (c_a - jnp.dot(v_a, w, preferred_element_type=acc,
+                          precision=_EXACT)).astype(c.dtype)
+
+
+def larft_body(gram: Array, taus: Array) -> Array:
+    """Block reflector T (``DLARFT``, forward, columnwise) from the
+    reflector Gram matrix ``V^T V`` — the kernel-safe realization.
+
+    Column i of T is ``-tau_i T[:, :i] (V[:, :i]^T v_i)`` with ``tau_i``
+    on the diagonal.  Only the strictly-lower triangle of ``gram`` is
+    read (its diagonal never enters T), so callers may pass the Gram of
+    V without its unit diagonal.  Every step is an iota mask and a
+    masked reduction, as in :func:`panel_body`: no dynamic gather,
+    scatter or mat-vec, which Mosaic cannot lower.  The host/jnp
+    realization is :func:`repro.core.blocked.larft`.
+    """
+    b = gram.shape[0]
+    acc = acc_dtype(gram.dtype)
+    g = gram.astype(acc)
+    rows = lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    rvec = lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (1, b), 1)
+    tau_row = taus.reshape(1, b).astype(acc)
+
+    def body(i, t):
+        tau_i = jnp.sum(jnp.where(lane == i, tau_row, 0.0), axis=1,
+                        keepdims=True)                          # (1, 1)
+        w = jnp.sum(jnp.where((rows == i) & (cols < i), g, 0.0), axis=0,
+                    keepdims=True)                              # (1, b)
+        tcol = jnp.sum(t * w, axis=1, keepdims=True)            # (b, 1)
+        tcol = jnp.where(rvec < i, -tau_i * tcol,
+                         jnp.where(rvec == i, tau_i, 0.0))
+        return jnp.where(cols == i, tcol, t)
+
+    t = lax.fori_loop(0, b, body, jnp.zeros((b, b), acc))
+    return t.astype(gram.dtype)
+
+
+def _gram(v: Array) -> Array:
+    v_a = v.astype(acc_dtype(v.dtype))
+    return _tdot(v_a, v_a)
+
+
+def _unpack_v1(packed: Array) -> Array:
+    """Unit-lower V1 of a packed square tile (2-D iota masks)."""
+    nb = packed.shape[0]
+    rows = lax.broadcasted_iota(jnp.int32, (nb, nb), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (nb, nb), 1)
+    return (jnp.where(rows > cols, packed, 0.0)
+            + jnp.where(rows == cols, 1.0, 0.0)).astype(packed.dtype)
 
 
 def stacked_larft(v2: Array, taus: Array) -> Array:
-    """Block reflector T for the stacked TSQRT reflectors V = [I; V2]."""
-    nb = v2.shape[1]
-    return larft(jnp.concatenate([jnp.eye(nb, dtype=v2.dtype), v2], axis=0),
-                 taus)
+    """Block reflector T for the stacked TSQRT reflectors V = [I; V2].
+    Off the diagonal the Gram of ``[I; V2]`` is ``V2^T V2``."""
+    return larft_body(_gram(v2), taus).astype(v2.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -187,14 +250,13 @@ def geqrt_body(tile: Array) -> Tuple[Array, Array, Array]:
     the diagonal, plus the WY block reflector for the step's LARFBs.
     """
     packed, taus = panel_body(tile, 0)
-    v1 = unpack_v_panel(packed, 0)
-    return packed, larft(v1, taus), taus
+    return packed, larft_body(_gram(_unpack_v1(packed)), taus), taus
 
 
 def larfb_body(diag_packed: Array, t: Array, c: Array) -> Array:
     """LARFB: apply Q_k^T to one trailing tile from the packed diagonal
     tile (V1 unpacked in place — the tile ref is the input)."""
-    return wy_body(unpack_v_panel(diag_packed, 0), t, c)
+    return wy_body(_unpack_v1(diag_packed), t, c)
 
 
 def tsqrt_factor(diag: Array, sub: Array) -> Tuple[Array, Array, Array]:
@@ -267,10 +329,10 @@ def ssrfb_body(v2: Array, t: Array, ck: Array, ci: Array
     v_a = v2.astype(acc)
     ck_a = ck.astype(acc)
     ci_a = ci.astype(acc)
-    w = ck_a + jnp.dot(v_a.T, ci_a, preferred_element_type=acc)
-    w = jnp.dot(t.astype(acc).T, w, preferred_element_type=acc)
+    w = _tdot(t.astype(acc), ck_a + _tdot(v_a, ci_a))
     return ((ck_a - w).astype(ck.dtype),
-            (ci_a - jnp.dot(v_a, w, preferred_element_type=acc)
+            (ci_a - jnp.dot(v_a, w, preferred_element_type=acc,
+                            precision=_EXACT)
              ).astype(ci.dtype))
 
 
@@ -293,17 +355,19 @@ def _copy(src, dst, sem) -> None:
 
 
 def geqrt_wavefront_kernel(kk_ref, ws_in, dt_in, dtaus_in,
-                           ws_out, dt_out, dtaus_out, tile_scr, sem):
+                           ws_out, dt_out, dtaus_out, tile_scr, taus_scr,
+                           sem):
     """One GEQRT task per grid cell: tile (k, k) -> packed, T, taus."""
     del ws_in, dt_in, dtaus_in  # aliased: reads go through the out refs
     g = pl.program_id(0)
     k = kk_ref[g]
     _copy(ws_out.at[k, k], tile_scr, sem)
     packed, t, taus = geqrt_body(tile_scr[...])
-    tile_scr[...] = packed
-    _copy(tile_scr, ws_out.at[k, k], sem)
     dt_out[0] = t
-    dtaus_out[0] = taus
+    tile_scr[...] = packed
+    taus_scr[...] = taus
+    _copy(tile_scr, ws_out.at[k, k], sem)
+    _copy(taus_scr, dtaus_out.at[k], sem)
 
 
 def larfb_wavefront_kernel(kk_ref, jj_ref, ws_in, dt_ref,
@@ -320,7 +384,8 @@ def larfb_wavefront_kernel(kk_ref, jj_ref, ws_in, dt_ref,
 
 
 def tsqrt_wavefront_kernel(kk_ref, ii_ref, ws_in, tt_in, ttaus_in,
-                           ws_out, tt_out, ttaus_out, diag_scr, sub_scr, sem):
+                           ws_out, tt_out, ttaus_out, diag_scr, sub_scr,
+                           taus_scr, sem):
     """One TSQRT task per grid cell: stacked QR of tiles (k,k) / (i,k)."""
     del ws_in, tt_in, ttaus_in
     g = pl.program_id(0)
@@ -329,12 +394,13 @@ def tsqrt_wavefront_kernel(kk_ref, ii_ref, ws_in, tt_in, ttaus_in,
     _copy(ws_out.at[k, k], diag_scr, sem)
     _copy(ws_out.at[i, k], sub_scr, sem)
     merged, v2, t, taus = tsqrt_body(diag_scr[...], sub_scr[...])
+    tt_out[0, 0] = t
     diag_scr[...] = merged
     sub_scr[...] = v2
+    taus_scr[...] = taus
     _copy(diag_scr, ws_out.at[k, k], sem)
     _copy(sub_scr, ws_out.at[i, k], sem)
-    tt_out[0, 0] = t
-    ttaus_out[0, 0] = taus
+    _copy(taus_scr, ttaus_out.at[i, k], sem)
 
 
 def ssrfb_wavefront_kernel(kk_ref, ii_ref, jj_ref, ws_in, tt_ref,

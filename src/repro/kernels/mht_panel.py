@@ -37,7 +37,9 @@ from typing import Tuple
 
 import jax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.plan import KERNEL_VMEM_LIMIT
 from repro.kernels import macro_ops
 
 Array = jax.Array
@@ -75,6 +77,8 @@ def mht_panel_pallas(
             pl.BlockSpec((m, b), lambda: (0, 0)),
             pl.BlockSpec((1, b), lambda: (0, 0)),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=KERNEL_VMEM_LIMIT),
         interpret=interpret,
     )(panel)
     return out, taus[0]

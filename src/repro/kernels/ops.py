@@ -32,12 +32,20 @@ from repro.kernels.wy_trailing import wy_trailing_pallas
 
 Array = jax.Array
 
-__all__ = ["mht_panel", "wy_trailing", "vmem_bytes_mht_panel", "default_interpret"]
+__all__ = ["mht_panel", "wy_trailing", "vmem_bytes_mht_panel",
+           "vmem_bytes_wy_trailing", "default_interpret"]
 
 
 def vmem_bytes_mht_panel(m: int, b: int) -> int:
     """fp32 working set of the panel kernel (panel + packed copy)."""
     return 2 * m * b * 4
+
+
+def vmem_bytes_wy_trailing(m: int, k: int, bn: int = 128) -> int:
+    """fp32 working set of one trailing-update step: V, T, the C tile in
+    and out, and W.  V's k columns occupy whole 128-lane rows in VMEM."""
+    lanes = -(-k // 128) * 128
+    return (m * lanes + k * lanes + 2 * m * bn + k * bn) * 4
 
 
 # The kernel backend registers its dispatch policy (VMEM estimator +
@@ -90,7 +98,7 @@ def wy_trailing(v: Array, t: Array, c: Array, *, bn: int = 128,
 
     Oracle: :func:`repro.kernels.ref.wy_trailing_ref`."""
     m, k = v.shape
-    if (m * bn + m * k + k * k + k * bn) * 4 > _POLICY.vmem_budget:
+    if vmem_bytes_wy_trailing(m, k, bn) > _POLICY.vmem_budget:
         raise ValueError(f"wy_trailing working set too large for VMEM: m={m} k={k} bn={bn}")
     interp = default_interpret() if interpret is None else interpret
     bn_eff = min(bn, max(8, c.shape[1]))

@@ -25,7 +25,9 @@ from __future__ import annotations
 
 import jax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.plan import KERNEL_VMEM_LIMIT
 from repro.kernels import macro_ops
 
 Array = jax.Array
@@ -59,5 +61,7 @@ def wy_trailing_pallas(
             pl.BlockSpec((m, bn), lambda j: (0, j)),  # C tile streams
         ],
         out_specs=pl.BlockSpec((m, bn), lambda j: (0, j)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=KERNEL_VMEM_LIMIT),
         interpret=interpret,
     )(v, t, c)

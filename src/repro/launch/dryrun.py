@@ -1,11 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above run before ANY other import — jax locks the device
-count at first init, and the production meshes need 512 host devices.
-(Only this entry point does so; tests and benches see 1 device.)
+The lines above run before ANY other import — jax locks the platform and
+the device count at first init, and the production meshes need 512 host
+devices.  The CPU platform is explicit, and the ``--all`` children inherit
+it: they compile against virtual host devices, and on a machine with a
+TPU none of them may try to take the chip.  (Only this entry point does
+so; tests and benches see 1 device.)
 
 Per cell:
     with mesh:

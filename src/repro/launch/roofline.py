@@ -1,11 +1,11 @@
 """Roofline analysis: compute / memory / collective terms per dry-run cell.
 
-Hardware model (TPU v5e per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware model: the published per-chip peaks in :data:`PEAKS`, keyed by
+``device_kind``; the dry-run cells model :data:`MODEL_DEVICE` (TPU v5e).
 
-    compute_s    = FLOPs / (chips * 197e12)
-    memory_s     = HBM_bytes / (chips * 819e9)
-    collective_s = collective_bytes / (chips * 50e9)
+    compute_s    = FLOPs / (chips * peak FLOP/s)
+    memory_s     = HBM_bytes / (chips * HBM bytes/s)
+    collective_s = collective_bytes / (ICI bytes/s per link)
 
 FLOPs and HBM bytes are ANALYTIC, derived from the architecture and cell
 shape: ``compiled.cost_analysis()`` counts every ``lax.scan`` body once
@@ -39,12 +39,46 @@ import numpy as np
 from repro.configs import ARCHS, SHAPES, get_config
 from repro.configs.base import ModelConfig, ShapeConfig
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link / chip
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published peaks of one chip."""
+
+    flops: float     # bf16 FLOP/s
+    hbm_bw: float    # HBM bytes/s
+    ici_bw: float    # chip-to-chip bytes/s per link
+    source: str
+
+
+#: Per-chip peaks keyed by ``jax.devices()[0].device_kind``.
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interconnect per chip over its four links
+        ici_bw=1600e9 / 8 / 4,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI'),
+}
+
+#: The chip the dry-run cells and the tuner's relative pruning model.
+MODEL_DEVICE = "TPU v5 lite"
+
+
+def peaks(device_kind: str = MODEL_DEVICE) -> DevicePeaks:
+    """Peaks of ``device_kind``; a kind not in :data:`PEAKS` is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to roofline.PEAKS "
+                       f"with their source") from None
+
 
 __all__ = ["analytic_cell_cost", "roofline_row", "build_table", "main",
-           "modeled_seconds", "qr_flops"]
+           "modeled_seconds", "qr_flops", "DevicePeaks", "PEAKS",
+           "MODEL_DEVICE", "peaks"]
 
 
 # ----------------------------------------------------- generic roofline
@@ -57,14 +91,14 @@ def qr_flops(m: int, n: int) -> float:
     return 2.0 * k * k * (max(m, n) - k / 3.0)
 
 
-def modeled_seconds(flops: float, hbm_bytes: float, *,
-                    chips: int = 1) -> float:
+def modeled_seconds(flops: float, hbm_bytes: float, *, chips: int = 1,
+                    device_kind: str = MODEL_DEVICE) -> float:
     """Roofline lower bound on one kernel: the dominant of the compute
-    and HBM terms under the per-chip hardware model above.  Absolute
-    numbers are TPU-calibrated; the tuner uses it *relatively* (prune
-    candidates whose bound already loses by a wide factor), where the
-    asymptotics carry over across backends."""
-    return max(flops / (chips * PEAK_FLOPS), hbm_bytes / (chips * HBM_BW))
+    and HBM terms under ``device_kind``'s peaks.  The tuner uses it
+    *relatively* (prune candidates whose bound already loses by a wide
+    factor), where the asymptotics carry over across backends."""
+    pk = peaks(device_kind)
+    return max(flops / (chips * pk.flops), hbm_bytes / (chips * pk.hbm_bw))
 
 
 # ------------------------------------------------------------- flop model
@@ -266,7 +300,8 @@ def _state_bytes(cfg: ModelConfig, b: int) -> float:
 
 # ------------------------------------------------------------- table
 
-def roofline_row(artifact: dict, *, chips: Optional[int] = None) -> dict:
+def roofline_row(artifact: dict, *, chips: Optional[int] = None,
+                 device_kind: str = MODEL_DEVICE) -> dict:
     arch, shape_name = artifact["arch"], artifact["shape"]
     cfg = get_config(arch)
     if artifact.get("variant") == "optimized":
@@ -280,9 +315,10 @@ def roofline_row(artifact: dict, *, chips: Optional[int] = None) -> dict:
     # (x while trip counts) when available, else static
     coll = artifact.get("collectives", {})
     coll_per_shard = coll.get("total_weighted_bytes") or coll.get("total_bytes", 0)
-    compute_s = cost.flops / (chips * PEAK_FLOPS)
-    memory_s = cost.hbm_bytes / (chips * HBM_BW)
-    collective_s = coll_per_shard / ICI_BW      # per-chip link time
+    pk = peaks(device_kind)
+    compute_s = cost.flops / (chips * pk.flops)
+    memory_s = cost.hbm_bytes / (chips * pk.hbm_bw)
+    collective_s = coll_per_shard / pk.ici_bw   # per-chip link time
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)
@@ -292,7 +328,7 @@ def roofline_row(artifact: dict, *, chips: Optional[int] = None) -> dict:
     # cell reach".  Raising it means either shrinking the dominant
     # non-compute term or shrinking compute waste (remat, masked attention
     # blocks, MoE capacity padding).
-    mfu_bound = (cost.model_flops / (chips * PEAK_FLOPS * bound_s)
+    mfu_bound = (cost.model_flops / (chips * pk.flops * bound_s)
                  if bound_s > 0 else 0.0)
     row = dict(
         arch=arch, shape=shape_name, mesh=artifact["mesh"], kind=kind,

@@ -19,6 +19,7 @@ import json
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.data import DataConfig
 from repro.distributed.sharding import MeshRules, activation_policy
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = rules = None

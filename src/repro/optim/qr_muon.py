@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.householder import form_q, unpack_r
-from repro.core.plan import QRConfig, plan as qr_plan
+from repro.core.plan import QRConfig, f32_products, plan as qr_plan
 from repro.optim.newton_schulz import newton_schulz_orthogonalize
 
 Array = jax.Array
@@ -90,6 +90,7 @@ def _pad_to(x: Array, mult: int) -> Array:
     return jnp.concatenate([x, jnp.zeros((x.shape[0], pad), x.dtype)], 1)
 
 
+@f32_products
 def qr_orthogonalize_2d(m_in: Array, *, block: int = 64,
                         q_method: str = "formq",
                         config: Optional[QRConfig] = None) -> Array:

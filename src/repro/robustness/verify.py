@@ -106,6 +106,14 @@ def _report(residual: float, defect: float, tol: float,
 
 
 # --------------------------------------------------------- jitted stats
+# Products at full precision: a check must not be coarser than the
+# factorization it judges (XLA on TPU would otherwise run a float32
+# product as one bfloat16 pass).
+
+def _mm(x, y):
+    return jnp.matmul(x, y, precision=jax.lax.Precision.HIGHEST)
+
+
 # One compiled program per (batch, m, n, k, dtype) signature; jit's own
 # cache keys on shapes so repeated buckets reuse their executable.
 
@@ -114,11 +122,11 @@ def _qr_stats(a, q, r):
     """Per-slice (relative residual, orthogonality defect) over a
     leading batch axis.  Empty (all-zero) padding slices report 0/0."""
     b = a.shape[0]
-    resid = jnp.linalg.norm((a - q @ r).reshape(b, -1), axis=-1)
+    resid = jnp.linalg.norm((a - _mm(q, r)).reshape(b, -1), axis=-1)
     scale = jnp.linalg.norm(a.reshape(b, -1), axis=-1)
     rel = jnp.where(scale > 0, resid / jnp.maximum(scale, 1e-300), resid)
     k = q.shape[-1]
-    gram = jnp.swapaxes(q, -1, -2) @ q - jnp.eye(k, dtype=q.dtype)
+    gram = _mm(jnp.swapaxes(q, -1, -2), q) - jnp.eye(k, dtype=q.dtype)
     defect = jnp.linalg.norm(gram.reshape(b, -1), axis=-1)
     return rel, defect
 
@@ -128,8 +136,8 @@ def _r_stats(a, r):
     """Per-slice Gram residual ||A^T A - R^T R||_F / ||A||_F^2 plus an
     upper-triangularity defect (relative mass below the diagonal)."""
     b = a.shape[0]
-    ata = jnp.swapaxes(a, -1, -2) @ a
-    rtr = jnp.swapaxes(r, -1, -2) @ r
+    ata = _mm(jnp.swapaxes(a, -1, -2), a)
+    rtr = _mm(jnp.swapaxes(r, -1, -2), r)
     resid = jnp.linalg.norm((ata - rtr).reshape(b, -1), axis=-1)
     scale = jnp.linalg.norm(a.reshape(b, -1), axis=-1) ** 2
     rel = jnp.where(scale > 0, resid / jnp.maximum(scale, 1e-300), resid)
@@ -144,7 +152,7 @@ def _r_stats(a, r):
 def _ortho_stats(q):
     b = q.shape[0]
     k = q.shape[-1]
-    gram = jnp.swapaxes(q, -1, -2) @ q - jnp.eye(k, dtype=q.dtype)
+    gram = _mm(jnp.swapaxes(q, -1, -2), q) - jnp.eye(k, dtype=q.dtype)
     return jnp.linalg.norm(gram.reshape(b, -1), axis=-1)
 
 

@@ -217,6 +217,13 @@ class QRService:
         self.policy = BucketingPolicy() if policy is None else policy
         self.use_kernel = (jax.default_backend() == "tpu"
                            if use_kernel is None else bool(use_kernel))
+        if self.use_kernel and jax.default_backend() == "tpu":
+            # Compiled kernels need a lane-aligned tile (the planner's
+            # ``tpu_tile_lane_aligned`` rule); buckets pad to it.
+            from repro.core.engine import lane_aligned_tile
+
+            self.policy = dataclasses.replace(
+                self.policy, tile=lane_aligned_tile(self.policy.tile))
         self.dispatch_mode = dispatch_mode
         self.interpret = interpret
         self.cache_size = cache_size
@@ -385,7 +392,8 @@ class QRService:
         shape = jax.ShapeDtypeStruct((batch, key.m, key.n),
                                      np.dtype(key.dtype))
         t0 = time.monotonic()
-        compiled = fn.lower(shape).compile()
+        with jax.default_matmul_precision("highest"):  # as QRSolver.solve
+            compiled = fn.lower(shape).compile()
         self._count("compiles")
         self._observe("compile_seconds", time.monotonic() - t0)
         return _BucketPlan(key=key, batch=batch, grid=(p, q), nb=nb,

@@ -156,7 +156,7 @@ def modeled_bound_us(cfg, m: int, n: int, dtype) -> float:
     (the engine's per-dispatch-mode DMA model for tiled; compulsory
     read+write for the dense methods)."""
     from repro.core import engine
-    from repro.launch.roofline import modeled_seconds, qr_flops
+    from repro.launch.roofline import MODEL_DEVICE, modeled_seconds, qr_flops
 
     itemsize = np.dtype(dtype).itemsize
     flops = qr_flops(m, n)
@@ -174,7 +174,9 @@ def modeled_bound_us(cfg, m: int, n: int, dtype) -> float:
         hbm = 2.0 * min(m, n) * m * n * itemsize / 2.0
     else:
         hbm = 2.0 * (m * n + m * min(m, n) + min(m, n) * n) * itemsize
-    return 1e6 * modeled_seconds(flops, hbm)
+    # Relative pruning: the bound's shape, modeled on one chip's peaks
+    # whatever backend the sweep measures on.
+    return 1e6 * modeled_seconds(flops, hbm, device_kind=MODEL_DEVICE)
 
 
 def prune_candidates(cands: Sequence[Tuple[str, "object"]], m: int, n: int,

@@ -56,7 +56,7 @@ def _plan_or_skip(shape, dtype, cfg):
 
 
 def _x64():
-    return jax.experimental.enable_x64()
+    return jax.enable_x64(True)
 
 
 class _nullctx:

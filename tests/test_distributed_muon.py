@@ -16,7 +16,6 @@ _SCRIPT = textwrap.dedent("""
     from repro.core.tsqr import distributed_qr
     from repro.optim import muon_init, muon_update, qr_orthogonalize_2d
 
-    from repro.compat import shard_map
     mesh = jax.make_mesh((8,), ("data",))
 
     # the distributed orthogonalizer: rows sharded over "data", thin Q out
@@ -24,7 +23,7 @@ _SCRIPT = textwrap.dedent("""
         rows = m2d.shape[0]
         transpose = m2d.shape[0] < m2d.shape[1]
         a = m2d.T if transpose else m2d
-        f = shard_map(lambda x: distributed_qr(x, "data"),
+        f = jax.shard_map(lambda x: distributed_qr(x, "data"),
                       mesh=mesh, in_specs=P("data", None),
                       out_specs=(P("data", None), P()))
         q, r = f(a)

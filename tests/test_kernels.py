@@ -201,6 +201,27 @@ def test_ssrfb_matches_ref(nb):
     np.testing.assert_allclose(np.asarray(ci_k), np.asarray(ci_r), atol=3e-5)
 
 
+@pytest.mark.parametrize("dtype,rtol", [("float32", 2e-5), ("float64", 1e-12)])
+@pytest.mark.parametrize("m,b", [(8, 8), (64, 16), (256, 32), (128, 128)])
+def test_larft_body_matches_blocked_larft(m, b, dtype, rtol):
+    """The kernel-safe T-formation (iota masks and masked reductions over
+    the reflector Gram, as the engine's kernels run it) equals the
+    host realization ``blocked.larft``, in float32 and float64."""
+    from repro.kernels.macro_ops import larft_body
+
+    with jax.enable_x64(dtype == "float64"):
+        a = jnp.asarray(np.random.default_rng(m + b).standard_normal((m, b)),
+                        dtype)
+        pf, taus = panel_factor(a, 0)
+        v = unpack_v_panel(pf, 0)
+        gram = jnp.matmul(v.T, v, precision=jax.lax.Precision.HIGHEST)
+        t_kernel = larft_body(gram, taus)
+        t_host = larft(v, taus)
+        assert t_kernel.dtype == t_host.dtype == jnp.dtype(dtype)
+        np.testing.assert_allclose(np.asarray(t_kernel), np.asarray(t_host),
+                                   rtol=rtol, atol=rtol)
+
+
 def test_tile_ops_vmem_guards():
     big = 2048  # 6 * 2048^2 * 4 bytes > the shared 8 MiB budget
     z = jnp.zeros((big, big), jnp.float32)

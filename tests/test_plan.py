@@ -129,14 +129,18 @@ def test_kernel_fits_gate_prices_wavefront_floor():
     wavefront floor: an fp64 shape whose wavefront set fits must keep
     use_kernel on TPU even though the megakernel set would not (auto
     then pins the wavefront lowering) — the megakernel is an opt-in
-    upgrade, never a reason to lose the kernel path."""
-    nb = 288
+    upgrade, never a reason to lose the kernel path.  TPU tiles are
+    lane-aligned (multiples of 128): at 384 the fp64 megakernel set
+    (15 tiles) is over the 8 MiB budget and the wavefront set (7) is
+    under it; at 256 the fp32 megakernel set fits."""
+    nb = 384
     shape = (4 * nb, 2 * nb)
     s64 = plan(shape, jnp.float64, QRConfig(method="tiled", block=nb),
                backend="tpu")
     assert s64.config.use_kernel is True
+    assert s64.config.block == nb
     assert s64.config.dispatch_mode == "wavefront"
-    s32 = plan(shape, jnp.float32, QRConfig(method="tiled", block=nb),
+    s32 = plan(shape, jnp.float32, QRConfig(method="tiled", block=256),
                backend="tpu")
     assert s32.config.use_kernel is True
     assert s32.config.dispatch_mode == "megakernel"
@@ -278,6 +282,26 @@ def test_plan_explain_cpu_floor_fallback_reason():
     assert "tiled_min_dim_cpu_floor" in solver.explain.fallback_reasons
     d = solver.explain.decision("tiled_min_dim_cpu_floor")
     assert d.outcome == "fallback" and "cpu" in d.reason.lower()
+
+
+@pytest.mark.parametrize("shape,method", [((512, 512), "tiled"),
+                                          ((4096, 4096), "sharded_tiled")])
+def test_plan_tpu_kernel_tile_is_lane_aligned(shape, method):
+    """The TPU compiler refuses the engine's tile DMAs below 128 lanes, so
+    on TPU the kernel path never runs the ``block=32`` default: the tile
+    is raised and the explain trail names the rule that raised it.  Off
+    TPU (interpret mode has no such limit) the tile stays as asked."""
+    kw = {"ndevices": 4} if method == "sharded_tiled" else {}
+    solver = plan(shape, jnp.float32, QRConfig(), backend="tpu",
+                  explain=True, **kw)
+    assert solver.config.method == method and solver.config.use_kernel
+    assert solver.config.block % 128 == 0
+    d = solver.explain.decision("tpu_tile_lane_aligned")
+    assert d.outcome == "resolved" and "32 -> 128" in d.reason
+    cpu = plan(shape, jnp.float32, QRConfig(method=method, use_kernel=True),
+               backend="cpu", explain=True, **kw)
+    assert "tpu_tile_lane_aligned" not in [
+        x.rule for x in cpu.explain.decisions]
 
 
 def test_plan_explain_sharded_degraded_reason():

@@ -553,6 +553,7 @@ def test_train_lm_fault_tolerance_drill(tmp_path):
         + ["--checkpoint-dir", str(tmp_path / "ckpt")],
         capture_output=True, text=True, timeout=900,
         env={"PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache"),
              "PATH": "/usr/bin:/bin:/usr/local/bin"},
         cwd=__file__.rsplit("/", 2)[0])
     out = res.stdout

@@ -141,11 +141,10 @@ def test_compressed_psum_subprocess():
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum, init_error_state
 
-        from repro.compat import shard_map
         mesh = jax.make_mesh((4,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 256), jnp.float32)
         err = jnp.zeros((4, 256), jnp.float32)
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda gg, ee: compressed_psum({"g": gg}, "data", {"g": ee}),
             mesh=mesh, in_specs=(P("data"), P("data")),
             out_specs=({"g": P()}, {"g": P("data")})))

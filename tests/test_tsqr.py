@@ -80,13 +80,12 @@ _SHARDED_SCRIPT = textwrap.dedent(
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core.tsqr import distributed_qr, tsqr_tree_sharded
 
-    from repro.compat import shard_map
     mesh = jax.make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.standard_normal((256, 16)), jnp.float32)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda x: distributed_qr(x, "data"),
             mesh=mesh,
             in_specs=P("data", None),
@@ -98,7 +97,7 @@ _SHARDED_SCRIPT = textwrap.dedent(
     assert np.linalg.norm(np.asarray(q).T @ np.asarray(q) - np.eye(16)) < 1e-3
 
     g = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda x: tsqr_tree_sharded(x, "data"),
             mesh=mesh,
             in_specs=P("data", None),

@@ -303,3 +303,21 @@ def test_sweep_small_shape_end_to_end(tmp_path):
                   explain=True)
     assert solver.explain.selected.rule == "tuned"
     assert solver.config.method == e.best.method
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """Peaks come from one table keyed by ``device_kind``; a kind not in
+    it raises instead of borrowing another chip's numbers.  The tuner's
+    relative pruning names the entry it models, so it works on CPU."""
+    from repro.launch import roofline
+    from repro.tuning.sweep import modeled_bound_us
+
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.peaks("cpu")
+    with pytest.raises(KeyError):
+        roofline.modeled_seconds(1.0, 1.0, device_kind="cpu")
+    assert modeled_bound_us(QRConfig(method="geqrf"), 256, 256,
+                            np.float32) > 0
