@@ -166,8 +166,10 @@ def main():
 
     # 4c. observability: plan(explain=True) attaches the machine-readable
     #     routing trail (why THIS method, every fallback by name), and
-    #     the off-by-default tracer records nested spans — exportable as
-    #     Chrome trace JSON — while the always-on metrics registry holds
+    #     the off-by-default tracer records nested host spans — never
+    #     blocking on the device, exportable as Chrome trace JSON, and
+    #     recorded into any jax.profiler capture on the device trace's
+    #     clock — while the always-on metrics registry holds
     #     planner/engine/serving counters.  Disabled, the layer is free:
     #     the megakernel jaxpr is identical either way (pinned in tests).
     from repro import observability as obs
@@ -193,7 +195,8 @@ def main():
     with obs.enabled_scope():                    # tracing + annotations on
         service.submit_many(mix)
     print(f"{'tracing':10s} {len(obs.spans())} spans "
-          f"(serving flush: bucketize -> plan -> dispatch -> unpad); "
+          f"(serving.submit: admit -> bucketize -> plan -> stage -> "
+          f"dispatch -> unpad); "
           f"obs.export_chrome_trace('trace.json') renders in "
           f"chrome://tracing, `python -m repro.observability.report "
           f"--capture DIR` bundles trace + metrics")

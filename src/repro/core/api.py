@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.plan import QRConfig, plan
+from repro.observability import trace as _trace
 
 Array = jax.Array
 
@@ -66,25 +67,31 @@ def qr(a: Array, *, config: Optional[QRConfig] = None
     "full" -> (Q m x m, R m x n).  Inputs with leading batch dims
     (``a.ndim > 2``) are factorized batch-wise via the solver's vmap rule.
     ``config=None`` plans with ``QRConfig()`` (method "auto").
+
+    Spans (host time, never blocking): ``qr.call`` around the whole call,
+    ``qr.plan`` around the planner.
     """
     if a.ndim < 2:
         raise ValueError(f"qr expects a matrix, got shape {a.shape}")
     cfg = _DEFAULT if config is None else config
-    solver = plan(a.shape, a.dtype, cfg)
-    if cfg.verify is not False and not isinstance(a, jax.core.Tracer):
-        # Health-checked path (QRConfig.verify / $REPRO_VERIFY): verify
-        # the planned result and walk the degradation ladder on failure
-        # (repro.robustness.escalate).  Resolution is host-side and
-        # never fires under a trace, so verify-off stays jaxpr-identical
-        # to solver.solve — the lazy import keeps the robustness layer
-        # out of the import graph until the knob is actually on.
-        from repro.robustness.verify import verify_enabled
+    with _trace.span("qr.call", shape=a.shape):
+        with _trace.span("qr.plan"):
+            solver = plan(a.shape, a.dtype, cfg)
+        if cfg.verify is not False and not isinstance(a, jax.core.Tracer):
+            # Health-checked path (QRConfig.verify / $REPRO_VERIFY):
+            # verify the planned result and walk the degradation ladder
+            # on failure (repro.robustness.escalate).  Resolution is
+            # host-side and never fires under a trace, so verify-off
+            # stays jaxpr-identical to solver.solve — the lazy import
+            # keeps the robustness layer out of the import graph until
+            # the knob is actually on.
+            from repro.robustness.verify import verify_enabled
 
-        if verify_enabled(cfg.verify):
-            from repro.robustness.escalate import checked_solve
+            if verify_enabled(cfg.verify):
+                from repro.robustness.escalate import checked_solve
 
-            return checked_solve(solver, a)
-    return solver.solve(a)
+                return checked_solve(solver, a)
+        return solver.solve(a)
 
 
 def orthogonalize(m_in: Array, *, config: Optional[QRConfig] = None) -> Array:

@@ -203,11 +203,11 @@ def sharded_tiled_qr(a: Array, *, tile: int = 32, mode: str = "reduced",
     k = min(m, n)
     with _obs_trace.span("distgraph.sharded_tiled_qr", domains=d,
                          shape=f"{m}x{n}", tile=tile,
-                         merge_levels=merge_levels(d)) as sp:
+                         merge_levels=merge_levels(d)):
         if mode == "r":
-            return sp.sync(fn(a_pad)[:k, :n])
+            return fn(a_pad)[:k, :n]
         q, r = fn(a_pad)
-        return sp.sync((q[:m, :k], r[:k, :n]))
+        return q[:m, :k], r[:k, :n]
 
 
 # -- registry -----------------------------------------------------------------

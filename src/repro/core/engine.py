@@ -78,7 +78,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import macro_ops
 from repro.observability import metrics as _metrics
 from repro.observability import profiler as _profiler
-from repro.observability import trace as _trace
 
 Array = jax.Array
 
@@ -1046,10 +1045,8 @@ def factor_tiles(tiles: Array, *, p: int, q: int, nb: int,
     if interpret is None:
         interpret = macro_ops.default_interpret()
     _emit_factor_metrics(tiles, p, q, nb, mode, bool(use_kernel))
-    with _trace.span("engine.factor_tiles", mode=mode, grid=f"{p}x{q}",
-                     nb=nb, kernel=bool(use_kernel)) as sp:
-        return sp.sync(_factor_jit(tiles, p, q, nb, bool(use_kernel),
-                                   bool(interpret), mode))
+    return _factor_jit(tiles, p, q, nb, bool(use_kernel), bool(interpret),
+                       mode)
 
 
 def _check_dispatch(dtype, p: int, q: int, nb: int, use_kernel: bool,
@@ -1140,8 +1137,5 @@ def factor_tiles_batched(tiles: Array, *, p: int, q: int, nb: int,
         interpret = macro_ops.default_interpret()
     _emit_factor_metrics(tiles, p, q, nb, mode, bool(use_kernel),
                          batch=int(tiles.shape[0]))
-    with _trace.span("engine.factor_tiles_batched", mode=mode,
-                     grid=f"{p}x{q}", nb=nb, batch=int(tiles.shape[0]),
-                     kernel=bool(use_kernel)) as sp:
-        return sp.sync(_factor_batched_jit(tiles, p, q, nb, bool(use_kernel),
-                                           bool(interpret), mode))
+    return _factor_batched_jit(tiles, p, q, nb, bool(use_kernel),
+                               bool(interpret), mode)

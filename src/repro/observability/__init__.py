@@ -6,11 +6,14 @@ Off by default and zero-cost when off; see ARCHITECTURE.md
     from repro import observability as obs
 
     obs.enable()                          # or REPRO_OBSERVABILITY=1
-    with obs.span("my.workload") as sp:
+    with obs.span("my.workload"):
         q, r = solver.solve(a)
-        sp.sync((q, r))
     obs.export_chrome_trace("trace.json")
     print(obs.metrics.to_prometheus())
+
+Spans time host work and never block on the device.  A
+``jax.profiler`` capture turns them on for its duration and records
+each into the profile's host plane, on the device trace's clock.
 
 Render a capture:  ``python -m repro.observability.report --help``
 """
@@ -20,8 +23,7 @@ from .instrument import (annotations_enabled, disable, enable, enabled_scope,
                          tracing_enabled)
 from .metrics import REGISTRY, counter, gauge, histogram, snapshot
 from .profiler import annotate, capture, kernel_label, megakernel_label
-from .trace import (chrome_trace, export_chrome_trace, span, spans, traced,
-                    tree)
+from .trace import chrome_trace, export_chrome_trace, span, spans, tree
 
 __all__ = [
     "REGISTRY",
@@ -45,7 +47,6 @@ __all__ = [
     "span",
     "spans",
     "trace",
-    "traced",
     "tracing_enabled",
     "tree",
 ]

@@ -1,11 +1,14 @@
 """Observability master switches — one place every instrumented call
 site checks before doing any work.
 
-The layer is **off by default**: with tracing disabled, span context
-managers are shared no-op singletons (no timestamps, no allocation, no
-``jax.block_until_ready``), and profiler annotations are
+The layer is **off by default**: with tracing disabled and no profiler
+session capturing, span context managers are shared no-op singletons
+(no timestamps, no allocation), and profiler annotations are
 ``contextlib.nullcontext`` (so jitted programs trace the *identical*
-jaxpr — pinned in tests/test_engine.py).  Metrics counters are always
+jaxpr — pinned in tests/test_engine.py).  A ``jax.profiler`` capture
+turns spans on for its duration (:mod:`repro.observability.trace`) but
+leaves annotations to their own switch, so a captured program is the
+program that runs uncaptured.  Metrics counters are always
 live: they are plain dict increments, cheap enough to be the substrate
 ``QRService.stats()`` sits on, and the serving tests rely on them
 unconditionally.
@@ -58,7 +61,8 @@ _LOCK = threading.Lock()
 
 
 def tracing_enabled() -> bool:
-    """Are host-side spans (and their JAX syncs) recording?"""
+    """Are host-side spans recording (besides during a profiler
+    capture, which records them too)?"""
     return _STATE.tracing
 
 

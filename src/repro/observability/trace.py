@@ -1,27 +1,38 @@
-"""Span tracer: nested timed regions exportable as Chrome trace JSON.
+"""Span tracer: nested host-timed regions, recorded into the JAX
+profiler's trace and exportable as Chrome trace JSON.
 
     from repro.observability import trace
 
-    with trace.span("serve.flush", bucket="64x64") as sp:
-        out = solve(batch)
-        sp.sync(out)            # block_until_ready ONLY while tracing
+    with trace.span("serving.stage", bucket="64x64", batch=8):
+        buf = stage(chunk)
 
     trace.export_chrome_trace("trace.json")   # load in chrome://tracing
 
 Design points:
 
-  * **Disabled = no-op.**  When tracing is off, :func:`span` returns a
-    shared ``_NullSpan`` singleton — no clock reads, no allocation, no
-    device sync.  The disabled path is one flag test, which the
-    overhead-budget test in tests/test_observability.py holds to < 1%
-    of the tiled 256² solve.
-  * **JAX-aware sync.**  ``sp.sync(x)`` calls ``jax.block_until_ready``
-    so the span measures device work, not dispatch — but skips it for
-    abstract tracers (spans inside a ``jit`` trace must not try to
-    block on values that don't exist yet).
+  * **On while tracing is enabled or a profiler session captures.**  A
+    span records when :func:`repro.observability.enable` turned tracing
+    on, or while ``jax.profiler`` is capturing (between ``start_trace``
+    and ``stop_trace``), so a captured profile always carries the
+    program's spans.  Otherwise :func:`span` returns a shared
+    ``_NullSpan`` singleton: two flag tests, no clock reads, no
+    allocation.  The overhead-budget test in tests/test_observability.py
+    holds that path to < 1% of the tiled 256² solve.
+  * **Two sinks, one span.**  An open span is also a
+    ``jax.profiler.TraceAnnotation`` under the same name, with its
+    labels as the event's stats, so it lands on the profiler's host
+    plane on the same clock as the device's ``XLA Ops``.  The completed
+    span is kept in memory for :func:`spans`, :func:`tree` and
+    :func:`chrome_trace`.
+  * **Never blocks.**  A span measures host time only: it waits for no
+    device value, so a traced program runs as an untraced one does.
+    The device side of the same interval is in the profiler's trace.
   * **Correct nesting.**  A thread-local stack gives every span a
     parent; depths and parent ids survive into the export, and
     :func:`tree` renders the hierarchy as text.
+
+Spans belong in host code: inside a jitted function a span times the
+trace, once per compile, not the execution.
 
 Export is the Chrome trace-event format: ``{"traceEvents": [...]}``
 with ``ph: "X"`` complete events, microsecond ``ts``/``dur``, ``pid`` /
@@ -30,12 +41,13 @@ with ``ph: "X"`` complete events, microsecond ``ts``/``dur``, ``pid`` /
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import instrument
 
@@ -46,7 +58,6 @@ __all__ = [
     "export_chrome_trace",
     "span",
     "spans",
-    "traced",
     "tree",
 ]
 
@@ -67,7 +78,7 @@ class Span:
     """One timed region.  Create via :func:`span`, not directly."""
 
     __slots__ = ("name", "labels", "sid", "parent_sid", "depth", "tid",
-                 "t_start", "t_end")
+                 "t_start", "t_end", "_annotation")
 
     def __init__(self, name: str, labels: Dict[str, Any]) -> None:
         self.name = name
@@ -78,6 +89,7 @@ class Span:
         self.tid = threading.get_ident()
         self.t_start = 0.0
         self.t_end = 0.0
+        self._annotation: Optional[TraceAnnotation] = None
 
     @property
     def duration_us(self) -> float:
@@ -85,19 +97,9 @@ class Span:
 
     def set(self, **labels: Any) -> "Span":
         self.labels.update(labels)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**labels)
         return self
-
-    def sync(self, value: Any) -> Any:
-        """Block until ``value``'s arrays are ready (skipping abstract
-        tracers), so the span covers device execution.  Returns value."""
-        import jax
-
-        if not isinstance(value, jax.core.Tracer):
-            try:
-                jax.block_until_ready(value)
-            except Exception:
-                pass  # non-array pytree leaves, tracers nested in pytrees
-        return value
 
     def __enter__(self) -> "Span":
         stack = _stack()
@@ -106,11 +108,15 @@ class Span:
             self.parent_sid = parent.sid
             self.depth = parent.depth + 1
         stack.append(self)
+        self._annotation = TraceAnnotation(self.name, **self.labels)
+        self._annotation.__enter__()
         self.t_start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.t_end = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation = None
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -134,36 +140,17 @@ class _NullSpan:
     def set(self, **labels: Any) -> "_NullSpan":
         return self
 
-    def sync(self, value: Any) -> Any:
-        return value
-
 
 _NULL_SPAN = _NullSpan()
 
 
 def span(name: str, **labels: Any):
-    """Context manager timing a region.  No-op singleton when disabled."""
-    if not instrument.tracing_enabled():
+    """Context manager timing a host region: a recorded :class:`Span`
+    while tracing is enabled or a profiler session captures, else the
+    shared no-op singleton."""
+    if not (instrument.tracing_enabled() or TraceAnnotation.is_enabled()):
         return _NULL_SPAN
     return Span(name, labels)
-
-
-def traced(name: Optional[str] = None, **labels: Any):
-    """Decorator form: ``@traced()`` or ``@traced("custom.name")``."""
-
-    def deco(fn):
-        span_name = name or f"{fn.__module__}.{fn.__qualname__}"
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            if not instrument.tracing_enabled():
-                return fn(*args, **kwargs)
-            with Span(span_name, dict(labels)):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
 
 
 def spans() -> List[Span]:

@@ -41,7 +41,8 @@ Routing per class is recorded in an :class:`OrthoPlan`:
 shapes — benchmarks and tests count dispatches from it without running
 anything.  :func:`batched_orthogonalize` executes the plan (inside jit:
 all grouping is static, only the padded stacks are traced), emitting
-``optim.*`` counters and spans through the observability registry.
+``optim.*`` counters through the observability registry (no spans: a
+span inside jit would time the trace, not the step).
 :func:`repro.optim.qr_muon.muon_update` rides on it behind the
 ``batched_ortho=True`` knob.
 """
@@ -58,7 +59,6 @@ import numpy as np
 
 from repro.core.plan import PlanExplain, QRConfig, plan as qr_plan
 from repro.observability import metrics as _metrics
-from repro.observability import trace as _trace
 from repro.serving.bucketing import (
     BucketKey, BucketingPolicy, group_shape_classes)
 
@@ -290,51 +290,42 @@ def batched_orthogonalize(leaves: Sequence[Array], *,
             geom.append((m, n, transpose))
 
     out: List[Optional[Array]] = [None] * len(members)
-    with _trace.span("optim.batched_ortho", classes=len(ortho_plan.classes),
-                     matrices=ortho_plan.n_matrices):
-        for cls in ortho_plan.classes:
-            _metrics.counter("optim.ortho_classes", route=cls.route).inc()
-            _metrics.counter("optim.ortho_dispatches",
-                             route=cls.route).inc(cls.dispatches)
-            _metrics.counter("optim.ortho_matrices",
-                             route=cls.route).inc(len(cls.members))
-            label = f"{cls.key.m}x{cls.key.n}"
-            if cls.route == "leafwise":
-                with _trace.span("optim.ortho_class", bucket=label,
-                                 route="leafwise", batch=len(cls.members)):
-                    for j in cls.members:
-                        m, n, transpose = geom[j]
-                        q = fallback(members[j].T if transpose
-                                     else members[j])
-                        out[j] = q.T if transpose else q
+    for cls in ortho_plan.classes:
+        _metrics.counter("optim.ortho_classes", route=cls.route).inc()
+        _metrics.counter("optim.ortho_dispatches",
+                         route=cls.route).inc(cls.dispatches)
+        _metrics.counter("optim.ortho_matrices",
+                         route=cls.route).inc(len(cls.members))
+        label = f"{cls.key.m}x{cls.key.n}"
+        if cls.route == "leafwise":
+            for j in cls.members:
+                m, n, transpose = geom[j]
+                q = fallback(members[j].T if transpose else members[j])
+                out[j] = q.T if transpose else q
+            continue
+        compute = np.dtype(cls.key.dtype)
+        solver = qr_plan((len(cls.members), cls.key.m, cls.key.n),
+                         compute, base, backend=backend)
+        stacked = jnp.stack([
+            jnp.pad(members[j].astype(compute),
+                    ((0, cls.key.m - geom[j][0]),
+                     (0, cls.key.n - geom[j][1])))
+            for j in cls.members])
+        q_stack = solver.orthogonalize(stacked)
+        q_stack, bad = _post_dispatch(q_stack, label, verify=base.verify)
+        for slot, j in enumerate(cls.members):
+            m, n, transpose = geom[j]
+            if slot in bad:
+                # Per-slice escalation: the batched dispatch's flagged
+                # slice alone re-solves leafwise; its class-mates ship
+                # as-is.
+                q = fallback(members[j].astype(compute)).astype(
+                    leaves[ortho_plan.member_leaf[j]].dtype)
+                out[j] = q.T if transpose else q
                 continue
-            compute = np.dtype(cls.key.dtype)
-            solver = qr_plan((len(cls.members), cls.key.m, cls.key.n),
-                             compute, base, backend=backend)
-            with _trace.span("optim.ortho_class", bucket=label,
-                             route="batched", batch=len(cls.members),
-                             method=solver.config.method):
-                stacked = jnp.stack([
-                    jnp.pad(members[j].astype(compute),
-                            ((0, cls.key.m - geom[j][0]),
-                             (0, cls.key.n - geom[j][1])))
-                    for j in cls.members])
-                q_stack = solver.orthogonalize(stacked)
-                q_stack, bad = _post_dispatch(q_stack, label,
-                                              verify=base.verify)
-                for slot, j in enumerate(cls.members):
-                    m, n, transpose = geom[j]
-                    if slot in bad:
-                        # Per-slice escalation: the batched dispatch's
-                        # flagged slice alone re-solves leafwise; its
-                        # class-mates ship as-is.
-                        q = fallback(members[j].astype(compute)).astype(
-                            leaves[ortho_plan.member_leaf[j]].dtype)
-                        out[j] = q.T if transpose else q
-                        continue
-                    q = q_stack[slot, :m, :n].astype(leaves[
-                        ortho_plan.member_leaf[j]].dtype)
-                    out[j] = q.T if transpose else q
+            q = q_stack[slot, :m, :n].astype(leaves[
+                ortho_plan.member_leaf[j]].dtype)
+            out[j] = q.T if transpose else q
 
     # Scatter members back into leaf-shaped stacks.
     results: List[Array] = []

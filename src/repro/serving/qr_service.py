@@ -243,6 +243,9 @@ class QRService:
         # Counters live in the process-global metrics registry under this
         # instance's ``service`` label; stats() is a view over them.
         self._sid = f"qr{next(_SERVICE_IDS)}"
+        # Ids of this service's flushes: the ``flush`` label that ties
+        # the spans of one flush together.
+        self._flush_ids = itertools.count()
 
     # ---------------------------------------------------- metrics plumbing
 
@@ -300,10 +303,19 @@ class QRService:
         """Submit a homogeneous-mode stream and flush it; results come
         back in submission order.  Buckets are dispatched back-to-back
         with the NEXT bucket's host->device transfer staged while the
-        current one computes (see :meth:`flush`)."""
-        rids = [self.submit(a, mode=mode) for a in arrays]
-        results = self.flush()
-        return [results[rid] for rid in rids]
+        current one computes (see :meth:`flush`).
+
+        Spans (host time, never blocking; labels ``service`` and
+        ``flush``): ``serving.submit`` around the call, holding
+        ``serving.admit`` (the :meth:`submit` loop) and the flush's own
+        spans."""
+        fid = next(self._flush_ids)
+        with _trace.span("serving.submit", service=self._sid, flush=fid,
+                         requests=len(arrays)):
+            with _trace.span("serving.admit", service=self._sid, flush=fid):
+                rids = [self.submit(a, mode=mode) for a in arrays]
+            results = self._flush(fid)
+            return [results[rid] for rid in rids]
 
     # --------------------------------------------------------- plan cache
 
@@ -493,16 +505,18 @@ class QRService:
         return out
 
     def _stage(self, key: BucketKey, chunk: List[QRRequest],
-               batch: int) -> Array:
+               batch: int, fid: int) -> Array:
         """Zero-pad and stack one chunk, then start its host->device
         transfer.  Unfilled batch slots stay zero — a zero matrix
         factors to zero reflectors, so padding slots are compute waste
         only, priced by the fill-ratio stat, never a correctness risk."""
-        buf = np.zeros((batch, key.m, key.n), np.dtype(key.dtype))
-        for s, req in enumerate(chunk):
-            m, n = req.shape
-            buf[s, :m, :n] = req.a
-        return jax.device_put(buf)
+        with _trace.span("serving.stage", service=self._sid, flush=fid,
+                         bucket=f"{key.m}x{key.n}", batch=batch):
+            buf = np.zeros((batch, key.m, key.n), np.dtype(key.dtype))
+            for s, req in enumerate(chunk):
+                m, n = req.shape
+                buf[s, :m, :n] = req.a
+            return jax.device_put(buf)
 
     def flush(self) -> Dict[int, QRResult]:
         """Execute every pending request; returns ``{rid: QRResult}``.
@@ -519,13 +533,17 @@ class QRService:
         Failure-atomic: if an exception escapes (escalation disabled or
         non-recoverable), every request not yet resolved to a result is
         restored to the pending queue before the exception propagates."""
+        return self._flush(next(self._flush_ids))
+
+    def _flush(self, fid: int) -> Dict[int, QRResult]:
+        """:meth:`flush`, its spans labelled with flush id ``fid``."""
         self._check_tuning()
-        with _trace.span("serving.bucketize", service=self._sid):
+        with _trace.span("serving.bucketize", service=self._sid, flush=fid):
             work = self._chunks()
         results: Dict[int, QRResult] = {}
         try:
             if work:
-                self._flush_work(work, results)
+                self._flush_work(work, results, fid)
         except BaseException:
             done = set(results)
             self._pending = [req for _, chunk in work for req in chunk
@@ -537,8 +555,9 @@ class QRService:
         self._quarantined.clear()
         return results
 
-    def _flush_work(self, work, results: Dict[int, QRResult]) -> None:
-        with _trace.span("serving.plan", service=self._sid,
+    def _flush_work(self, work, results: Dict[int, QRResult],
+                    fid: int) -> None:
+        with _trace.span("serving.plan", service=self._sid, flush=fid,
                          chunks=len(work)):
             planned = [self._plan_with_escalation(
                 key, pad_batch(len(chunk), max_batch=self.policy.max_batch))
@@ -550,7 +569,7 @@ class QRService:
         if kernel_chunks:
             i0 = kernel_chunks[0]
             staged[i0] = self._stage(work[i0][0], work[i0][1],
-                                     planned[i0][0].batch)
+                                     planned[i0][0].batch, fid)
         outs: Dict[int, object] = {}
         for pos, i in enumerate(kernel_chunks):
             plan, rung = planned[i]
@@ -558,11 +577,11 @@ class QRService:
             if pos + 1 < len(kernel_chunks):
                 j = kernel_chunks[pos + 1]
                 staged[j] = self._stage(work[j][0], work[j][1],
-                                        planned[j][0].batch)
+                                        planned[j][0].batch, fid)
             tag = f"{key.m}x{key.n}:{rung}"
             with _trace.span("serving.dispatch", service=self._sid,
-                             bucket=f"{key.m}x{key.n}", batch=plan.batch,
-                             fill=len(chunk), rung=rung):
+                             flush=fid, bucket=f"{key.m}x{key.n}",
+                             batch=plan.batch, fill=len(chunk), rung=rung):
                 try:
                     _inject.sleep(tag)
                     _inject.check("dispatch", tag)
@@ -592,7 +611,7 @@ class QRService:
             real = sum(m * n for m, n in (r.shape for r in chunk))
             waste = 1.0 - real / (plan.batch * key.m * key.n)
             self._observe("padding_waste", waste, bucket=f"{key.m}x{key.n}")
-        with _trace.span("serving.unpad", service=self._sid) as sp:
+        with _trace.span("serving.unpad", service=self._sid, flush=fid):
             for i, (key, chunk) in enumerate(work):
                 plan, rung = planned[i]
                 if rung == "recovered":
@@ -603,18 +622,6 @@ class QRService:
                     self._count("matrices_served", len(chunk))
                     continue
                 out = outs[i]
-                try:
-                    sp.sync(out)
-                except Exception as e:  # noqa: BLE001 — deferred runtime error
-                    if not self.escalate:
-                        raise
-                    self._record_escalation(key, _escalate.record(
-                        rung, "per-request",
-                        _escalate.classify(e, "dispatch"), str(e)))
-                    for req in chunk:
-                        results[req.rid] = self._recover_request(
-                            req, key, rung)
-                    continue
                 bad: Set[int] = set()
                 if verify_on:
                     bad = self._verify_chunk(key, chunk, out, rung)

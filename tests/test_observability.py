@@ -8,12 +8,14 @@ The layer's contract has two halves, and both are load-bearing:
     even a small (256²) tiled solve by an explicit budget assertion.
     The jaxpr-pin twin of this guarantee (annotations add zero
     equations to the megakernel lowering) lives in tests/test_engine.py.
-  * **Enabled is truthful.**  Spans nest correctly across the
-    thread-local stack, ``sync`` blocks on device values so durations
-    cover execution rather than dispatch, the Chrome-trace export
-    round-trips through JSON with the schema chrome://tracing loads,
-    and the metrics registry stays exact under concurrent writers —
-    including real ``QRService.submit_many`` traffic from threads.
+  * **Enabled is truthful and changes nothing.**  Spans nest correctly
+    across the thread-local stack, a profiler capture records them into
+    its host plane (labels as stats) with the layer itself off, a span
+    never waits for the device, so traced and untraced serving give the
+    same answers, the Chrome-trace export round-trips through JSON with
+    the schema chrome://tracing loads, and the metrics registry stays
+    exact under concurrent writers — including real
+    ``QRService.submit_many`` traffic from threads.
 """
 
 import json
@@ -162,37 +164,6 @@ def test_span_disabled_is_shared_noop_singleton():
     assert trace.spans() == []
 
 
-class _SyncProbe:
-    """Duck-typed array: records whether block_until_ready ran."""
-
-    def __init__(self):
-        self.blocked = False
-
-    def block_until_ready(self):
-        self.blocked = True
-        return self
-
-
-def test_sync_noop_when_disabled_blocks_when_enabled():
-    probe = _SyncProbe()
-    out = trace.span("x").sync(probe)
-    assert out is probe and not probe.blocked  # disabled: never syncs
-    with obs.enabled_scope():
-        with trace.span("x") as sp:
-            assert sp.sync(probe) is probe
-    assert probe.blocked  # enabled: span waits for the device
-
-
-def test_sync_skips_abstract_tracers():
-    with obs.enabled_scope():
-        def f(x):
-            with trace.span("inside.jit") as sp:
-                return sp.sync(x * 2.0)
-
-        out = jax.jit(f)(jnp.ones((4,)))
-    np.testing.assert_allclose(np.asarray(out), 2.0)
-
-
 def test_span_nesting_and_ordering():
     with obs.enabled_scope():
         with trace.span("outer", wave=0) as outer:
@@ -206,18 +177,6 @@ def test_span_nesting_and_ordering():
     assert a.depth == b.depth == 1 and outer.depth == 0
     assert outer.t_start <= a.t_start <= a.t_end <= b.t_start <= outer.t_end
     assert "outer" in trace.tree() and "  inner.a" in trace.tree()
-
-
-def test_traced_decorator():
-    @trace.traced("deco.name", kind="unit")
-    def work():
-        return 7
-
-    assert work() == 7  # disabled: plain call
-    with obs.enabled_scope():
-        assert work() == 7
-    (sp,) = trace.spans()
-    assert sp.name == "deco.name" and sp.labels == {"kind": "unit"}
 
 
 def test_chrome_trace_round_trip(tmp_path):
@@ -253,12 +212,186 @@ def test_enabled_scope_restores_prior_state():
     assert not instrument.tracing_enabled()
 
 
+def _host_events(log_dir, name):
+    """``(plane, stats)`` of every host-plane event named ``name`` in the
+    ``.xplane.pb`` a profiler session wrote under ``log_dir``."""
+    import glob
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(plane.name, dict(ev.stats)) for plane in pd.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events if ev.name == name]
+
+
+def test_profiler_capture_records_spans_with_layer_off(tmp_path):
+    """A span opened under ``jax.profiler.start_trace`` lands in the
+    trace's host plane under its own name, labels as stats, while the
+    observability layer itself stays disabled; it is kept in memory too."""
+    assert not instrument.tracing_enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("t.capture", bucket="64x64", batch=8) as sp:
+            sp.set(fill=5)
+    finally:
+        jax.profiler.stop_trace()
+    ((plane, stats),) = _host_events(tmp_path, "t.capture")
+    assert plane.startswith("/host:")
+    assert stats == {"bucket": "64x64", "batch": 8, "fill": 5}
+    (kept,) = trace.spans()
+    assert kept.name == "t.capture" and kept.duration_us >= 0
+    assert kept.labels == {"bucket": "64x64", "batch": 8, "fill": 5}
+
+
+def test_no_span_recorded_when_neither_switch_is_on(tmp_path):
+    """Neither tracing nor a profiler session: qr() and a serving flush
+    record nothing; after a capture ends spans are off again."""
+    from repro.core import qr
+    from repro.serving import BucketingPolicy, QRService
+
+    a = jnp.asarray(np.random.default_rng(3).standard_normal((32, 32)),
+                    jnp.float32)
+    svc = QRService(policy=BucketingPolicy(tile=16, max_batch=4),
+                    use_kernel=False)
+    jax.profiler.start_trace(str(tmp_path))
+    jax.profiler.stop_trace()
+    jax.block_until_ready(qr(a))
+    svc.submit_many([np.eye(8, dtype=np.float32)] * 3)
+    assert trace.span("t.off") is trace.span("t.off2")
+    assert trace.spans() == []
+
+
+class _SyncProbe:
+    """Duck-typed array: records whether block_until_ready ran."""
+
+    def __init__(self):
+        self.blocked = False
+
+    def block_until_ready(self):
+        self.blocked = True
+        return self
+
+
+def test_enabled_span_never_blocks(monkeypatch):
+    """A recording span waits for no device value: not for a value it
+    carries, and not in a traced serving flush (whose unpad used to
+    block only while tracing)."""
+    from repro.serving import BucketingPolicy, QRService
+
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(x) or real(x))
+    probe = _SyncProbe()
+    svc = QRService(policy=BucketingPolicy(tile=16, max_batch=4),
+                    use_kernel=False)
+    wave = [np.random.default_rng(4).standard_normal((12, 10))
+            .astype(np.float32) for _ in range(5)]
+    with obs.enabled_scope():
+        with trace.span("t.probe", value=probe):
+            pass
+        svc.submit_many(wave)
+    assert not probe.blocked
+    assert calls == []
+    assert any(s.name == "serving.unpad" for s in trace.spans())
+
+
+def test_qr_yields_call_with_plan_nested():
+    from repro.core import qr
+
+    a = jnp.asarray(np.random.default_rng(5).standard_normal((48, 40)),
+                    jnp.float32)
+    with obs.enabled_scope(annotations=False):
+        q, r = qr(a)
+    spans = trace.spans()
+    assert [s.name for s in spans] == ["qr.plan", "qr.call"]
+    plan_sp, call = spans
+    assert call.labels == {"shape": (48, 40)} and call.depth == 0
+    assert plan_sp.parent_sid == call.sid and plan_sp.depth == 1
+    assert call.t_start <= plan_sp.t_start <= plan_sp.t_end <= call.t_end
+    np.testing.assert_allclose(np.asarray(q @ r), np.asarray(a), atol=1e-4)
+
+
+def test_submit_many_spans_share_one_flush_and_change_no_answer():
+    """One traced ``submit_many`` yields ``serving.submit`` holding admit,
+    bucketize, plan, stage, dispatch and unpad, all with one ``flush``
+    label; the answers are bitwise those of an untraced flush."""
+    from repro.serving import BucketingPolicy, QRService
+
+    rng = np.random.default_rng(6)
+    wave = [rng.standard_normal(s).astype(np.float32)
+            for s in [(12, 10), (30, 30), (12, 12), (40, 20), (9, 9)]]
+    svc = QRService(policy=BucketingPolicy(tile=16, max_batch=2),
+                    use_kernel=False)
+    off = svc.submit_many(wave)
+    assert trace.spans() == []
+    with obs.enabled_scope(annotations=False):
+        on = svc.submit_many(wave)
+    spans = trace.spans()
+    (sub,) = [s for s in spans if s.name == "serving.submit"]
+    assert sub.labels["requests"] == len(wave) and sub.depth == 0
+    kids = [s for s in spans if s is not sub]
+    assert {s.name for s in kids} == {
+        "serving.admit", "serving.bucketize", "serving.plan",
+        "serving.stage", "serving.dispatch", "serving.unpad"}
+    assert all(s.parent_sid == sub.sid for s in kids)
+    assert {s.labels["flush"] for s in spans} == {sub.labels["flush"]}
+    assert {s.labels["service"] for s in spans} == {svc._sid}
+    stages = [s for s in kids if s.name == "serving.stage"]
+    assert len(stages) == sum(1 for s in kids
+                              if s.name == "serving.dispatch") > 1
+    for a, b in zip(off, on):
+        assert a.ok and b.ok
+        np.testing.assert_array_equal(np.asarray(a.q), np.asarray(b.q))
+        np.testing.assert_array_equal(np.asarray(a.r), np.asarray(b.r))
+
+
+def test_flush_ids_count_per_service():
+    from repro.serving import QRService
+
+    a = np.eye(8, dtype=np.float32)
+    svc = QRService(use_kernel=False)
+    with obs.enabled_scope(annotations=False):
+        svc.submit_many([a])
+        svc.submit(a)
+        svc.flush()
+        svc.submit_many([a])
+    flushes = [s.labels["flush"] for s in trace.spans()
+               if s.name == "serving.bucketize"]
+    assert flushes == [0, 1, 2]
+
+
+def test_profiler_capture_leaves_annotations_off(tmp_path):
+    """A capture turns spans on but not profiler annotations: a program
+    lowered during a capture carries no scope name, the same program as
+    one lowered outside it."""
+    from repro.observability import profiler
+
+    def lowered(debug_info):
+        def f(x):
+            with profiler.annotate("t.scope"):
+                return jnp.sin(x) * 2.0
+        return jax.jit(f).lower(jnp.ones((4,))).as_text(
+            debug_info=debug_info)
+
+    outside = lowered(False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        inside, inside_debug = lowered(False), lowered(True)
+    finally:
+        jax.profiler.stop_trace()
+    assert inside == outside and "t.scope" not in inside_debug
+    with obs.enabled_scope():
+        assert "t.scope" in lowered(True)  # the switch that turns them on
+
+
 # ----------------------------------------------------------------- overhead
 
 def test_disabled_overhead_budget():
-    """The disabled-mode budget: one span + sync (what a hot serving /
-    engine call adds) must cost < 1% of even a small tiled 256² solve.
-    Generous on both sides — the null path is ~1 µs, the solve is ms."""
+    """The disabled-mode budget: one labelled span (what a hot serving /
+    ``qr()`` boundary adds: two flag tests) must cost < 1% of even a
+    small tiled 256² solve.  Generous on both sides — the null path is
+    well under 1 µs, the solve is ms."""
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.standard_normal((256, 256), dtype=np.float32))
     solver = plan(a.shape, a.dtype,
@@ -272,8 +405,8 @@ def test_disabled_overhead_budget():
     n = 2000
     t0 = time.perf_counter()
     for _ in range(n):
-        with trace.span("overhead.probe", mode="megakernel") as sp:
-            sp.sync(None)
+        with trace.span("overhead.probe", mode="megakernel"):
+            pass
     per_call_s = (time.perf_counter() - t0) / n
     assert per_call_s < 0.01 * solve_s, (
         f"disabled span costs {per_call_s * 1e6:.2f} us/call, "
