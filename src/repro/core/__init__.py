@@ -1,7 +1,8 @@
 """repro.core — Householder/MHT QR factorization (the paper's contribution).
 
 Layers:
-    householder  classical HT (DGEQR2 semantics), Q application/formation
+    householder  classical HT (DGEQR2 semantics), Q application, and
+                 blocked WY Q formation (DORGQR)
     mht          Modified Householder Transform (fused macro-op updates)
     blocked      WY-blocked QR (DGEQRF / DGEQRFHT / fori_loop variant)
     tsqr         communication-avoiding distributed QR over mesh axes

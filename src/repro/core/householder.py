@@ -15,7 +15,10 @@ passes (GEMV then rank-1 update), mirroring the paper's Algorithm 2 where
 The Modified HT (paper §4) lives in :mod:`repro.core.mht`.
 
 Everything is shape-static and ``jit``-compatible: the column loop is a
-``lax.fori_loop`` over masked full-width operations.
+``lax.fori_loop`` over masked full-width operations.  :func:`form_q` is
+the exception: it forms Q from 128-wide block reflectors (LAPACK
+``DORGQR``, GEMMs on the MXU), while :func:`apply_q` keeps the
+reflector-by-reflector loop (``DORM2R``).
 """
 
 from __future__ import annotations
@@ -26,6 +29,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro.observability import metrics as _metrics
 
 Array = jax.Array
 
@@ -229,13 +234,56 @@ def apply_q(packed: Array, taus: Array, c: Array, *, transpose: bool = False) ->
     return c
 
 
+#: Panel width of Q formation: the MXU's width.
+Q_BLOCK = 128
+
+
 def form_q(packed: Array, taus: Array, *, full: bool = False) -> Array:
-    """Materialize Q — thin (m x k) by default, or full (m x m)."""
+    """Materialize Q — thin (m x k) by default, or full (m x m).
+
+    Blocked WY Q formation (LAPACK ``DORGQR``): starting from the
+    identity, the reflectors are applied back to front in panels of
+    :data:`Q_BLOCK`, each as one block reflector ``I - V T V^T`` through
+    three GEMMs.  Panel ``j0`` touches only ``Q[j0:, j0:]``: the rows and
+    columns above ``j0`` still hold the identity's.  One jitted program
+    per shape and ``full``; its products take the caller's matmul
+    precision.
+
+    Counters: ``householder.form_q`` (one per Q formed) and
+    ``householder.form_q_panels`` (panels applied), labelled
+    ``phase="execute"`` for a call on concrete arrays and
+    ``phase="trace"`` for one under an outer trace (counted once per
+    compiled program, as the ``engine.*`` series are).
+    """
+    phase = "trace" if isinstance(packed, jax.core.Tracer) else "execute"
+    _metrics.counter("householder.form_q", phase=phase).inc()
+    _metrics.counter("householder.form_q_panels", phase=phase).inc(
+        -(-taus.shape[0] // Q_BLOCK))
+    return _form_q_blocked(packed, taus, full=full)
+
+
+@functools.partial(jax.jit, static_argnames=("full",))
+def _form_q_blocked(packed: Array, taus: Array, *, full: bool) -> Array:
+    from repro.core.blocked import larft, unpack_v_panel, wy_apply  # lazy: blocked imports us
+
     m = packed.shape[0]
     k = taus.shape[0]
-    cols = m if full else k
-    eye = jnp.eye(m, cols, dtype=packed.dtype)
-    return apply_q(packed, taus, eye)
+    b = Q_BLOCK
+    nfull = k // b
+    q = jnp.eye(m, m if full else k, dtype=packed.dtype)
+    if nfull:
+        # T does not depend on Q: form it for every full panel in one
+        # vmapped DLARFT over full-height V (zeros above each pivot).
+        panels = packed[:, :nfull * b].reshape(m, nfull, b).transpose(1, 0, 2)
+        vs = jax.vmap(unpack_v_panel)(panels, jnp.arange(nfull) * b)
+        ts = jax.vmap(larft)(vs, taus[:nfull * b].reshape(nfull, b))
+    for j0 in reversed(range(0, k, b)):
+        bw = min(b, k - j0)
+        v = unpack_v_panel(packed[j0:, j0:j0 + bw], 0)
+        t = ts[j0 // b] if bw == b else larft(v, taus[j0:])
+        # wy_apply applies the block's transpose: hand it T^T.
+        q = q.at[j0:, j0:].set(wy_apply(v, t.T, q[j0:, j0:]))
+    return q
 
 
 # -- registry -----------------------------------------------------------------

@@ -109,8 +109,8 @@ def qr_orthogonalize_2d(m_in: Array, *, block: int = 64,
         form-Q to fp32 eps for optimizer-grade conditioning, and the
         diag-clamp handles rank deficiency.
       * "formq" (default — the paper-faithful baseline): accumulate
-        reflectors; exact even for singular input, but a min(m,n)-trip
-        sequential loop.
+        reflectors; exact even for singular input.  Blocked WY
+        (``householder.form_q``): one group of GEMMs per 128 reflectors.
 
     Accumulation runs in ``promote_types(param_dtype, float32)`` — bf16
     storage params factor in fp32 (and round back to bf16 on return),

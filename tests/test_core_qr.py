@@ -6,6 +6,8 @@ Paper claims under test:
   C4: blocked (WY) variants produce the same factorization as unblocked.
 """
 
+import math
+
 import numpy as np
 import pytest
 import jax
@@ -19,6 +21,7 @@ from repro.core import (
     geqr2,
     geqr2_ht,
     geqrf,
+    geqrf_fori,
     house_vector,
     lstsq,
     orthogonalize,
@@ -26,7 +29,9 @@ from repro.core import (
     qr_algorithm_eig,
     unpack_r,
 )
+from repro.core import householder
 from repro.core.householder import geqr2_explicit_p
+from repro.observability import metrics
 
 SHAPES = [(8, 8), (16, 8), (12, 5), (33, 17), (32, 32), (64, 48), (48, 64)]
 
@@ -111,6 +116,78 @@ def test_apply_q_transpose_roundtrip():
     c = _rand(24, 6, seed=4)
     back = apply_q(packed, taus, apply_q(packed, taus, c, transpose=True))
     np.testing.assert_allclose(np.asarray(back), np.asarray(c), atol=1e-4)
+
+
+# Every packed method's output feeds form_q.  geqrf_fori needs its block
+# to divide k, so it takes the largest power of two that does.
+_PACKED = {
+    "geqrf_ht": lambda a: geqrf(a, block=32, panel_method="mht"),
+    "geqr2": geqr2,
+    "geqrf_fori": lambda a: geqrf_fori(a, block=math.gcd(min(a.shape), 128)),
+}
+# f32 tolerance fixed beforehand: ~84 eps32, an order above the readings.
+_FORM_Q_ATOL = 1e-5
+
+
+def _check_form_q(q, packed, taus, cols):
+    """Q against the rank-1 reference (``apply_q`` on the identity), and
+    its orthogonality."""
+    m = packed.shape[0]
+    ref = apply_q(packed, taus, jnp.eye(m, cols, dtype=packed.dtype))
+    assert q.shape == (m, cols)
+    np.testing.assert_allclose(np.asarray(q), np.asarray(ref), atol=_FORM_Q_ATOL)
+    np.testing.assert_allclose(np.asarray(q.T @ q), np.eye(cols), atol=_FORM_Q_ATOL)
+
+
+@pytest.mark.parametrize("factor", sorted(_PACKED))
+@pytest.mark.parametrize("full", [False, True])
+@pytest.mark.parametrize("m,n", [(512, 512), (600, 300), (300, 600), (1000, 130),
+                                 (100, 60)])
+def test_form_q_blocked_matches_rank1(m, n, full, factor):
+    """Blocked WY Q formation (square, tall, wide, k not a multiple of the
+    128-wide panel, k below it) equals the reflector-by-reflector loop."""
+    a = _rand(m, n, seed=m + 3 * n)
+    packed, taus = _PACKED[factor](a)
+    cols = m if full else min(m, n)
+    _check_form_q(form_q(packed, taus, full=full), packed, taus, cols)
+
+
+def test_form_q_under_outer_jit():
+    """As QR-Muon calls it: inside the optimizer step's jit."""
+    a = _rand(600, 300, seed=21)
+    packed, taus = geqrf(a, block=32, panel_method="mht")
+    q = jax.jit(lambda p, t: form_q(p, t))(packed, taus)
+    _check_form_q(q, packed, taus, 300)
+
+
+def test_form_q_traces_once_per_shape():
+    """One program per shape: a second input of the same shape reuses it."""
+    impl = householder._form_q_blocked
+    sizes = [impl._cache_size()]
+    for seed in (31, 32):
+        packed, taus = geqr2(_rand(72, 40, seed=seed))
+        jax.block_until_ready(form_q(packed, taus))
+        sizes.append(impl._cache_size())
+    assert sizes[2] == sizes[1] == sizes[0] + 1
+
+
+@pytest.mark.parametrize("under_jit", [False, True])
+def test_form_q_counters(under_jit):
+    """One Q and ceil(k/128) panels per formation; an eager call counts at
+    execute, one inside jit once at trace."""
+    phase = "trace" if under_jit else "execute"
+
+    def count():
+        return (metrics.counter_value("householder.form_q", phase=phase),
+                metrics.counter_value("householder.form_q_panels", phase=phase))
+
+    f = jax.jit(lambda p, t: form_q(p, t)) if under_jit else form_q
+    q0, p0 = count()
+    for seed in (41, 42):
+        packed, taus = geqr2(_rand(600, 300, seed=seed))
+        jax.block_until_ready(f(packed, taus))
+    calls = 1 if under_jit else 2
+    assert count() == (q0 + calls, p0 + 3 * calls)
 
 
 @pytest.mark.parametrize("m,n", [(8, 8), (16, 8), (12, 5), (32, 32)])
