@@ -15,8 +15,13 @@ The pipeline per :meth:`QRService.flush`:
 
     requests -> admission -> bucketize -> (plan cache: BucketKey x batch
     -> compiled executable) -> stage bucket i+1's host->device transfer
-    while bucket i computes (donated input buffers) -> sync + health
-    check -> unpad + scatter results back
+    while bucket i computes (donated input buffers), and start bucket i's
+    device->host copy as soon as it is dispatched -> one host fetch per
+    output stack (padding slots included) + health check -> unpad into
+    NumPy views + scatter results back
+
+Answers are host ``np.ndarray`` views into their chunk's fetched
+stack: no device program and no device->host transfer per request.
 
 **Compiled-plan cache.**  Plans are AOT-compiled
 (``jax.jit(...).lower(...).compile()``) and kept in an LRU keyed on
@@ -113,19 +118,29 @@ class QRRequest:
 class QRResult:
     """Unpadded per-request answer; ``q`` is None for mode="r".
 
+    ``q`` and ``r`` are host ``np.ndarray``s on every path.  From a
+    batched chunk they are read-only views into that chunk's host copy
+    of its padded output stacks (fetched once per chunk): a caller that
+    keeps one answer alone and wants the rest of the chunk freed copies
+    it (``np.array(res.q)``).
+
     ``error`` is None for a healthy result; a quarantined or
     unrecoverable request carries the named reason
     (``"quarantined:nonfinite_input"``, ``"escalation_exhausted"``,
     ...) and ``q``/``r`` may be None."""
 
     rid: int
-    q: Optional[Array]
-    r: Optional[Array]
+    q: Optional[np.ndarray]
+    r: Optional[np.ndarray]
     error: Optional[str] = None
 
     @property
     def ok(self) -> bool:
         return self.error is None
+
+
+def _host(x) -> Optional[np.ndarray]:
+    return None if x is None else np.asarray(x)
 
 
 def _tuning_fingerprint() -> Tuple:
@@ -303,7 +318,10 @@ class QRService:
         """Submit a homogeneous-mode stream and flush it; results come
         back in submission order.  Buckets are dispatched back-to-back
         with the NEXT bucket's host->device transfer staged while the
-        current one computes (see :meth:`flush`).
+        current one computes (see :meth:`flush`).  Every answer's Q and
+        R are already on the host: read-only NumPy views into their
+        chunk's host copy (see :class:`QRResult`; copy one to free the
+        rest of its chunk).
 
         Spans (host time, never blocking; labels ``service`` and
         ``flush``): ``serving.submit`` around the call, holding
@@ -478,8 +496,18 @@ class QRService:
                             error="escalation_exhausted")
         for esc in escs:
             self._record_escalation(key, esc)
-        return QRResult(rid=req.rid, q=None if key.mode == "r" else q,
-                        r=r)
+        return QRResult(rid=req.rid, q=None if key.mode == "r" else _host(q),
+                        r=_host(r))
+
+    def _recover_chunk(self, key: BucketKey, chunk: List[QRRequest],
+                       rung: str, e: Exception, site: str,
+                       results: Dict[int, QRResult]) -> None:
+        """Record ``e``, raised at ``site`` for a whole chunk, and
+        recover each of its requests below ``rung``."""
+        self._record_escalation(key, _escalate.record(
+            rung, "per-request", _escalate.classify(e, site), str(e)))
+        for req in chunk:
+            results[req.rid] = self._recover_request(req, key, rung)
 
     def _lapack_chunk(self, key: BucketKey, chunk: List[QRRequest]
                       ) -> Dict[int, QRResult]:
@@ -489,7 +517,7 @@ class QRService:
         out: Dict[int, QRResult] = {}
         for req in chunk:
             q, r = _escalate.lapack_qr(req.a, key.mode)
-            out[req.rid] = QRResult(rid=req.rid, q=q, r=r)
+            out[req.rid] = QRResult(rid=req.rid, q=_host(q), r=_host(r))
         return out
 
     # ---------------------------------------------------------- execution
@@ -526,9 +554,16 @@ class QRService:
         buffer is already staging host->device; each staged buffer is
         donated into its executable (compiled with ``donate_argnums``),
         so steady state holds one in-flight compute and one in-flight
-        transfer, not a growing buffer population.  Health checks and
-        escalations happen at sync time, after every dispatch has been
-        issued — a failing slice never stalls the healthy pipeline.
+        transfer, not a growing buffer population.  Each chunk's output
+        stacks start their device->host copy as soon as it is
+        dispatched, overlapping the next chunk's staging and compute;
+        after every dispatch has been issued, each stack is fetched to
+        the host once, whole, and every request's answer is a NumPy view
+        into it (no per-request device program or transfer).  Health
+        checks and escalations happen then too — a failing slice never
+        stalls the healthy pipeline, and a device error that surfaces
+        at a chunk's fetch escalates that chunk's requests like a
+        dispatch error.
 
         Failure-atomic: if an exception escapes (escalation disabled or
         non-recoverable), every request not yet resolved to a result is
@@ -587,18 +622,15 @@ class QRService:
                     _inject.check("dispatch", tag)
                     out = plan.fn(staged.pop(i))  # async; donates buffer
                     outs[i] = _inject.corrupt_output(out, tag)
+                    for x in outs[i]:
+                        x.copy_to_host_async()
                 except Exception as e:  # noqa: BLE001
                     if not self.escalate:
                         raise
-                    # Dispatch raised before results existed: the whole
-                    # chunk recovers per request below this rung.
-                    self._record_escalation(key, _escalate.record(
-                        rung, "per-request", _escalate.classify(
-                            e, "dispatch"), str(e)))
+                    # Dispatch raised before results existed.
                     staged.pop(i, None)
-                    for req in chunk:
-                        results[req.rid] = self._recover_request(
-                            req, key, rung)
+                    self._recover_chunk(key, chunk, rung, e, "dispatch",
+                                        results)
                     planned[i] = (None, "recovered")
                     continue
             self._count("dispatches")
@@ -612,6 +644,21 @@ class QRService:
             waste = 1.0 - real / (plan.batch * key.m * key.n)
             self._observe("padding_waste", waste, bucket=f"{key.m}x{key.n}")
         with _trace.span("serving.unpad", service=self._sid, flush=fid):
+            # Every fetch comes before any answer, so a fetch error that
+            # raises (escalation off) leaves no request resolved.
+            hosts: Dict[int, Tuple[np.ndarray, ...]] = {}
+            for i in outs:
+                plan, rung = planned[i]
+                key, chunk = work[i]
+                try:
+                    hosts[i] = self._fetch(key, plan, outs[i], fid)
+                except Exception as e:  # noqa: BLE001
+                    if not self.escalate:
+                        raise
+                    # A device error deferred past dispatch surfaces here.
+                    self._recover_chunk(key, chunk, rung, e, "fetch",
+                                        results)
+                    planned[i] = (None, "recovered")
             for i, (key, chunk) in enumerate(work):
                 plan, rung = planned[i]
                 if rung == "recovered":
@@ -621,10 +668,10 @@ class QRService:
                     self._count("dispatches")
                     self._count("matrices_served", len(chunk))
                     continue
-                out = outs[i]
+                host = hosts[i]
                 bad: Set[int] = set()
                 if verify_on:
-                    bad = self._verify_chunk(key, chunk, out, rung)
+                    bad = self._verify_chunk(key, chunk, outs[i], rung)
                 now = time.monotonic()
                 for s, req in enumerate(chunk):
                     if s in bad:
@@ -634,12 +681,26 @@ class QRService:
                     m, n = req.shape
                     k = min(m, n)
                     if key.mode == "r":
-                        q_mat, r_mat = None, out[0][s, :k, :n]
+                        q_mat, r_mat = None, host[0][s, :k, :n]
                     else:
-                        q_mat, r_mat = out[0][s, :m, :k], out[1][s, :k, :n]
+                        q_mat, r_mat = host[0][s, :m, :k], host[1][s, :k, :n]
                     results[req.rid] = QRResult(rid=req.rid, q=q_mat,
                                                 r=r_mat)
                     self._observe("latency_seconds", now - req.t_submit)
+
+    def _fetch(self, key: BucketKey, plan: _BucketPlan, out,
+               fid: int) -> Tuple[np.ndarray, ...]:
+        """Materialize one chunk's padded output stacks on the host, each
+        once and whole (padding slots included), waiting only for the
+        copies :meth:`_flush_work` started at dispatch."""
+        nbytes = sum(int(x.nbytes) for x in out)
+        with _trace.span("serving.fetch", service=self._sid, flush=fid,
+                         bucket=f"{key.m}x{key.n}", batch=plan.batch,
+                         bytes=nbytes):
+            host = tuple(np.asarray(x) for x in out)
+        self._count("host_fetches", len(host))
+        self._count("fetch_bytes", nbytes)
+        return host
 
     def _verify_chunk(self, key: BucketKey, chunk: List[QRRequest],
                       out, rung: str) -> Set[int]:
@@ -683,7 +744,9 @@ class QRService:
         served over batch slots dispatched (1.0 = every slot carried a
         real request); ``cache_hit_rate`` is plan-cache hits over
         lookups; ``breaker_open`` counts buckets currently pinned to the
-        fallback path.
+        fallback path; ``host_fetches`` counts output stacks fetched to
+        the host (one per output of each dispatched chunk, never one per
+        request) and ``fetch_bytes`` the bytes they moved.
 
         Counters are a view over this instance's ``serving.*`` series in
         the process-global metrics registry (``service=<id>`` label)."""
@@ -711,4 +774,6 @@ class QRService:
                 "health_check_failures"),
             breaker_trips=self._count_value("breaker_trips"),
             breaker_open=len(self._breaker_open),
+            host_fetches=self._count_value("host_fetches"),
+            fetch_bytes=self._count_value("fetch_bytes"),
         )

@@ -314,8 +314,9 @@ def test_qr_yields_call_with_plan_nested():
 
 def test_submit_many_spans_share_one_flush_and_change_no_answer():
     """One traced ``submit_many`` yields ``serving.submit`` holding admit,
-    bucketize, plan, stage, dispatch and unpad, all with one ``flush``
-    label; the answers are bitwise those of an untraced flush."""
+    bucketize, plan, stage, dispatch and unpad, with one ``serving.fetch``
+    per dispatched chunk inside unpad, all with one ``flush`` label; the
+    answers are bitwise those of an untraced flush."""
     from repro.serving import BucketingPolicy, QRService
 
     rng = np.random.default_rng(6)
@@ -330,11 +331,16 @@ def test_submit_many_spans_share_one_flush_and_change_no_answer():
     spans = trace.spans()
     (sub,) = [s for s in spans if s.name == "serving.submit"]
     assert sub.labels["requests"] == len(wave) and sub.depth == 0
-    kids = [s for s in spans if s is not sub]
+    (unpad,) = [s for s in spans if s.name == "serving.unpad"]
+    fetches = [s for s in spans if s.name == "serving.fetch"]
+    kids = [s for s in spans if s is not sub and s.name != "serving.fetch"]
     assert {s.name for s in kids} == {
         "serving.admit", "serving.bucketize", "serving.plan",
         "serving.stage", "serving.dispatch", "serving.unpad"}
     assert all(s.parent_sid == sub.sid for s in kids)
+    assert all(s.parent_sid == unpad.sid for s in fetches)
+    assert len(fetches) == sum(1 for s in kids
+                               if s.name == "serving.dispatch")
     assert {s.labels["flush"] for s in spans} == {sub.labels["flush"]}
     assert {s.labels["service"] for s in spans} == {svc._sid}
     stages = [s for s in kids if s.name == "serving.stage"]

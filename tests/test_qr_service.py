@@ -306,3 +306,161 @@ def test_tuning_refresh_invalidates_plans():
         assert svc.stats()["compiles"] == compiles + 1
     finally:
         set_active_cache(prev)
+
+
+# ------------------------------------------------------------ host answers
+
+
+@pytest.fixture
+def no_faults():
+    from repro.robustness import inject
+
+    inject.reset()
+    yield inject
+    inject.reset()
+
+
+def _rand(shape, seed):
+    return np.random.default_rng(seed).standard_normal(shape).astype(
+        np.float32)
+
+
+def _slot_bytes(key):
+    """Bytes of one batch slot of a chunk's padded output stacks."""
+    r = key.m * key.n
+    q = 0 if key.mode == "r" else key.m * min(key.m, key.n)
+    return (q + r) * np.dtype(key.dtype).itemsize
+
+
+@pytest.mark.parametrize("mode", ["reduced", "r"])
+@pytest.mark.parametrize("path", ["kernel_chunk", "lapack_chunk",
+                                  "recovered"])
+def test_answers_are_host_arrays_on_every_path(path, mode, no_faults):
+    """A batched chunk, a breaker-pinned LAPACK chunk and a per-request
+    recovery all answer with host ``np.ndarray`` Q and R of the
+    unpadded shapes; mode "r" answers with ``q is None``."""
+    inject = no_faults
+    svc = QRService(policy=BucketingPolicy(tile=16, max_batch=4),
+                    use_kernel=False, verify=True, breaker_threshold=1)
+    arrs = [_rand((24, 12), s) for s in range(3)]
+    if path == "lapack_chunk":          # trip the breaker, then serve
+        with inject.active(inject.Fault(site="dispatch", match="32x16")):
+            svc.submit_many([_rand((24, 12), 9)], mode=mode)
+        assert svc.stats()["breaker_open"] == 1
+    fault = (inject.Fault(site="output", match="32x16", slice_index=1)
+             if path == "recovered" else None)
+    if fault is None:
+        results = svc.submit_many(arrs, mode=mode)
+    else:
+        with inject.active(fault):
+            results = svc.submit_many(arrs, mode=mode)
+    for a, res in zip(arrs, results):
+        assert res.ok
+        assert isinstance(res.r, np.ndarray) and res.r.shape == (12, 12)
+        if mode == "r":
+            assert res.q is None
+        else:
+            assert isinstance(res.q, np.ndarray) and res.q.shape == (24, 12)
+            _check_qr(a, res.q, res.r)
+    fetches = svc.stats()["host_fetches"]
+    if path == "kernel_chunk":
+        assert fetches == (1 if mode == "r" else 2)
+        assert all(not res.r.flags.writeable for res in results)
+    elif path == "lapack_chunk":
+        assert fetches == 0
+    else:
+        assert svc.stats()["health_check_failures"] == 1
+
+
+@pytest.mark.parametrize("mode", ["reduced", "r"])
+@pytest.mark.parametrize("nreq", [1, 3, 4, 9, 16])
+def test_one_host_fetch_per_chunk_output(nreq, mode):
+    """A flush of ``nreq`` same-bucket requests fetches each chunk's
+    output stacks once — 2 per chunk (1 for mode "r"), whatever the
+    number of requests — and counts their padded bytes."""
+    policy = BucketingPolicy(tile=16, max_batch=4)
+    svc = QRService(policy=policy, use_kernel=False)
+    results = svc.submit_many([_rand((24, 12), s) for s in range(nreq)],
+                              mode=mode)
+    assert all(res.ok for res in results)
+    sizes = [min(4, nreq - i) for i in range(0, nreq, 4)]
+    st = svc.stats()
+    assert st["dispatches"] == len(sizes)
+    assert st["host_fetches"] == len(sizes) * (1 if mode == "r" else 2)
+    key = bucket_key(24, 12, np.float32, mode, policy)
+    assert st["fetch_bytes"] == sum(
+        pad_batch(b, max_batch=4) for b in sizes) * _slot_bytes(key)
+
+
+@pytest.mark.parametrize("mode", ["reduced", "r"])
+def test_answers_equal_padded_output_slices_bitwise(mode, monkeypatch):
+    """Each answer is bit for bit its slice of the chunk's padded device
+    outputs, sliced on the device as a per-request unpad would."""
+    svc = QRService(policy=BucketingPolicy(tile=16, max_batch=4),
+                    use_kernel=False)
+    seen = []
+    real = QRService._fetch
+
+    def spy(self, key, plan, out, fid):
+        seen.append(out)
+        return real(self, key, plan, out, fid)
+
+    monkeypatch.setattr(QRService, "_fetch", spy)
+    shapes = [(24, 12), (20, 9), (30, 16), (17, 17), (40, 20)]
+    arrs = [_rand(s, k) for k, s in enumerate(shapes)]
+    results = svc.submit_many(arrs, mode=mode)
+    assert len(seen) == svc.stats()["dispatches"]
+    # Requests fill their bucket's chunk in submission order.
+    by_key, slot = {}, []
+    for a in arrs:
+        key = bucket_key(*a.shape, a.dtype, mode, svc.policy)
+        slot.append((key, by_key.setdefault(key, []).__len__()))
+        by_key[key].append(a)
+    order = list(by_key)
+    for a, res, (key, s) in zip(arrs, results, slot):
+        out = seen[order.index(key)]
+        m, n = a.shape
+        k = min(m, n)
+        np.testing.assert_array_equal(
+            res.r, np.asarray(out[-1][s, :k, :n]))
+        if mode != "r":
+            np.testing.assert_array_equal(
+                res.q, np.asarray(out[0][s, :m, :k]))
+
+
+@pytest.mark.parametrize("escalate", [True, False])
+def test_fetch_error_escalates_or_restores(escalate, monkeypatch):
+    """A device error surfacing at a chunk's fetch: with escalation on,
+    every request of the chunk recovers per request (``fetch_failed``);
+    with it off the error propagates and every request of the flush is
+    back in the queue, including those of chunks fetched before it."""
+    svc = QRService(policy=BucketingPolicy(tile=16, max_batch=2),
+                    use_kernel=False, escalate=escalate)
+    real = QRService._fetch
+    calls = []
+
+    def failing(self, key, plan, out, fid):
+        calls.append(key)
+        if len(calls) == 2:
+            raise RuntimeError("device error at fetch")
+        return real(self, key, plan, out, fid)
+
+    monkeypatch.setattr(QRService, "_fetch", failing)
+    arrs = [_rand((24, 12), s) for s in range(4)]      # two chunks
+    if not escalate:
+        rids = [svc.submit(a) for a in arrs]
+        with pytest.raises(RuntimeError, match="device error at fetch"):
+            svc.flush()
+        assert sorted(r.rid for r in svc._pending) == rids
+        monkeypatch.setattr(QRService, "_fetch", real)
+        res = svc.flush()
+        for rid, a in zip(rids, arrs):
+            _check_qr(a, res[rid].q, res[rid].r)
+        return
+    results = svc.submit_many(arrs)
+    for a, res in zip(arrs, results):
+        assert res.ok and isinstance(res.q, np.ndarray)
+        _check_qr(a, res.q, res.r)
+    assert [e.rule for e in svc.escalations] == ["fetch_failed"]
+    assert svc.stats()["escalations"] == 1
+    assert svc.stats()["host_fetches"] == 2           # the first chunk's
